@@ -18,6 +18,10 @@ correctness condition behind it asserted:
    commits. Results must agree at 1e-5.
 
 CI uploads the JSON as an artifact (same convention as ``BENCH_api.json``).
+
+This is a CPU multi-process rehearsal: it imports JAX and then spawns CPU
+child processes (``JAX_PLATFORMS=cpu``), and a TPU belongs to one process at
+a time, so do not run it on a chip host. Its times are CPU wall clocks.
 """
 from __future__ import annotations
 

@@ -15,6 +15,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         api_bench,
         bigdata_kmeans,

@@ -226,11 +226,19 @@ class _MomentReducer:
         self._step_parts = []
 
     def _assemble_step(self) -> SparseRows:
-        """The buffered step as one SparseRows: a plain host concat on a
-        single host; under multi-process, the local shards' rows become this
-        process's addressable block of a global row-sharded array."""
+        """The buffered step as one SparseRows, row-sharded over the mesh so
+        each device holds its own shards' rows (a ragged step is left for the
+        reduction to pad and place); under multi-process, the local shards'
+        rows become this process's addressable block of a global row-sharded
+        array."""
         if not _is_multiprocess():
-            return _concat_sparse(self._step_parts, self.spec.p_pad)
+            s = _concat_sparse(self._step_parts, self.spec.p_pad)
+            if s.n % self.plan.n_shards:
+                return s
+            axes = (self.plan.axis,)
+            return SparseRows(sharded_mod.shard_rows(s.values, self._mesh, axes),
+                              sharded_mod.shard_rows(s.indices, self._mesh, axes),
+                              s.p)
         from repro import cluster
 
         vals = np.concatenate([np.asarray(s.values) for s in self._step_parts])

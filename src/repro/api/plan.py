@@ -18,6 +18,7 @@ from typing import Any, Literal
 import jax
 
 from repro.core import ros, sketch
+from repro.launch.mesh import auto_mesh
 
 Backend = Literal["batch", "stream", "sharded"]
 
@@ -144,7 +145,7 @@ class Plan:
 
 @functools.lru_cache(maxsize=None)
 def _auto_mesh(n_shards: int, axis: str):
-    return jax.make_mesh((n_shards,), (axis,))
+    return auto_mesh((n_shards,), (axis,))
 
 
 # ----------------------------------------------------------- mesh (de)spec --
@@ -168,4 +169,4 @@ def mesh_from_spec(spec: dict | None):
     if the host has too few)."""
     if spec is None:
         return None
-    return jax.make_mesh(tuple(spec["shape"]), tuple(spec["axis_names"]))
+    return auto_mesh(spec["shape"], spec["axis_names"])

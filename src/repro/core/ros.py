@@ -144,10 +144,10 @@ def precondition(x: jax.Array, key: jax.Array, transform: Transform = "hadamard"
     d = signs_for(key, pp, dtype=x.dtype)
     impl = resolve_impl(impl)
     if impl != "jnp" and transform == "hadamard":
-        from repro.kernels import fwht as _fwht  # deferred: kernels import this module
+        from repro.kernels import ops as kops  # deferred: kernels import this module
 
         lead = x.shape[:-1]
-        y = _fwht.hd_precondition(x.reshape(-1, pp), d, interpret=(impl == "interpret"))
+        y = kops.hd_precondition(x.reshape(-1, pp), d, mode=impl)
         return y.reshape(*lead, pp)
     return apply_h(x * d, transform)
 

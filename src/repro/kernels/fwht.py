@@ -13,6 +13,10 @@ the MXU. For p ≤ 256 a single dense H_p matmul is used (a = 1).
 
 The kernel tiles rows; each grid step owns a (block_rows, p) tile resident in
 VMEM. H_a, H_b (and the sign vector) are small and replicated to every step.
+Its row block (:func:`default_block_rows`) counts what Mosaic allocates,
+the lane-padded Kronecker intermediates included, so every power of two p
+from 2^8 to 2^15 compiles for v5e under the default scoped-VMEM limit; the
+chunked schedule below compiles at 2^16 and 2^17.
 
 **Large p (the streaming regime, p > 2^15):** a (block_rows, p) tile no longer
 fits VMEM, so the transform is *chunked* with the three-factor identity
@@ -38,6 +42,7 @@ from jax.experimental import pallas as pl
 from repro.core.ros import hadamard_matrix
 
 # largest p the single-tile kernel supports: (block_rows × p) must fit VMEM.
+# Every power of two from 2^8 to 2^15 compiles for v5e at default_block_rows.
 MAX_P_SINGLE = 1 << 15
 # largest p overall — the chunked three-pass schedule with factors ≤ 2^9.
 MAX_P = 1 << 27
@@ -72,41 +77,81 @@ def factor_p3(p: int) -> tuple[int, int, int]:
     return 1 << ka, 1 << kb, 1 << kc
 
 
-def default_block_rows(p: int, dtype=jnp.float32, vmem_budget: int = 6 << 20) -> int:
-    """Rows per tile so that in+out tiles fit the VMEM budget."""
-    bytes_per_row = 2 * p * jnp.dtype(dtype).itemsize
-    br = max(8, vmem_budget // max(1, bytes_per_row))
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB; every kernel in this
+# package plans its tiles against 12 MiB of it and raises no limit.
+VMEM_BUDGET = 12 << 20
+
+# Every kernel dot in this package contracts at full f32 precision. Mosaic's
+# default runs an f32 × f32 dot as one bf16 pass, which put the kernels 2e-3
+# to 3e-3 (max-abs relative) off their f32 oracles on a v5e; the Hadamard
+# factors' ±1/√b entries are not bf16 numbers.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def tile_bytes_per_row(p: int, dtype=jnp.float32) -> int:
+    """VMEM one row of the single-tile kernel's (block_rows, p) tile costs.
+
+    - the input and output blocks, each double-buffered by the pipeline:
+      4·p·itemsize;
+    - the Kronecker intermediates, ``width`` elements a row: p for a = 1,
+      else b·pad128(a), because the outer-factor contraction's a-wide minor
+      axis is padded to 128 lanes (16× at p = 1024). At full f32
+      contraction precision (MXU_PRECISION) Mosaic
+      holds several bf16 parts of each; the smallest ``vmem_limit_bytes``
+      each tile compiles under for a described v5e (p = 256…32768, 8…64
+      rows) comes to at most 21.4 bytes per intermediate element, modelled
+      as 22.
+    """
+    a, b = factor_p(p)
+    width = p if a == 1 else b * max(a, 128)
+    return 4 * p * jnp.dtype(dtype).itemsize + 22 * width
+
+
+def default_block_rows(p: int, dtype=jnp.float32, extra_bytes_per_row: int = 0) -> int:
+    """Rows per tile (a power of two in [8, 256]) so the tile fits VMEM_BUDGET.
+
+    ``extra_bytes_per_row`` is what a kernel sharing this body adds per row
+    (kernels.sketch_fused).
+    """
+    per_row = tile_bytes_per_row(p, dtype) + extra_bytes_per_row
+    br = max(8, VMEM_BUDGET // per_row)
     return int(min(256, 1 << int(np.floor(np.log2(br)))))
 
 
-def _kernel(x_ref, d_ref, ha_ref, hb_ref, o_ref, *, a: int, b: int):
-    x = x_ref[...] * d_ref[...]                              # sign flip (D), fused
+def hd_tile(x, d_ref, ha_ref, hb_ref, *, a: int, b: int):
+    """H·(d ⊙ x) of a (rows, a·b) tile as Kronecker-factored MXU dots (f32)."""
+    x = x * d_ref[...]                                       # sign flip (D), fused
     bn = x.shape[0]
     f32 = jnp.float32
     if a == 1:
-        y = jax.lax.dot(x, hb_ref[...], preferred_element_type=f32)
-    else:
-        # inner factor: contract the trailing b axis with H_b
-        y = jax.lax.dot(x.reshape(bn * a, b), hb_ref[...], preferred_element_type=f32)
-        # outer factor: contract the a axis with H_a
-        y = y.reshape(bn, a, b).transpose(0, 2, 1).reshape(bn * b, a)
-        y = jax.lax.dot(y, ha_ref[...], preferred_element_type=f32)
-        y = y.reshape(bn, b, a).transpose(0, 2, 1).reshape(bn, a * b)
-    o_ref[...] = y.astype(o_ref.dtype)
+        return jax.lax.dot(x, hb_ref[...], precision=MXU_PRECISION,
+                           preferred_element_type=f32)
+    # inner factor: contract the trailing b axis with H_b
+    y = jax.lax.dot(x.reshape(bn * a, b), hb_ref[...], precision=MXU_PRECISION,
+                    preferred_element_type=f32)
+    # outer factor: contract the a axis with H_a
+    y = y.reshape(bn, a, b).transpose(0, 2, 1).reshape(bn * b, a)
+    y = jax.lax.dot(y, ha_ref[...], precision=MXU_PRECISION,
+                    preferred_element_type=f32)
+    return y.reshape(bn, b, a).transpose(0, 2, 1).reshape(bn, a * b)
+
+
+def _kernel(x_ref, d_ref, ha_ref, hb_ref, o_ref, *, a: int, b: int):
+    o_ref[...] = hd_tile(x_ref[...], d_ref, ha_ref, hb_ref, a=a, b=b).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------- chunked three-pass ----
 
 def _pass_kernel(x_ref, h_ref, o_ref):
     o_ref[...] = jax.lax.dot(
-        x_ref[...], h_ref[...], preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+        x_ref[...], h_ref[...], precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def _pass_signs_kernel(x_ref, s_ref, h_ref, o_ref):
     o_ref[...] = jax.lax.dot(
-        x_ref[...] * s_ref[...], h_ref[...], preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+        x_ref[...] * s_ref[...], h_ref[...], precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def _factor_pass(z: jax.Array, h: jax.Array, block_rows: int, interpret: bool,

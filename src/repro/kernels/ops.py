@@ -1,9 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels, with backend auto-selection.
 
-On TPU the compiled kernels run natively; elsewhere (this CI container is
-CPU-only) they execute via ``interpret=True`` (Pallas interpreter) or fall back
-to the jnp oracles for speed. Call sites in core/ go through these wrappers so
-the backend choice is one switch.
+Modes: ``kernel`` (compiled Pallas, TPU only), ``interpret`` (the Pallas
+interpreter, any backend) and ``ref`` (the jnp oracles of kernels.ref).
+``auto`` resolves to ``kernel`` on a TPU and to ``ref`` elsewhere. Call sites
+in core/ go through these wrappers so the backend choice is one switch, and
+every resolved choice is counted (``kernels.dispatch{op=,path=}``): a run on
+the chip that shows any ``ref`` or ``interpret`` dispatch did not run the
+kernels. An explicit ``kernel`` request that the kernel cannot serve raises;
+nothing is demoted silently.
 """
 from __future__ import annotations
 
@@ -59,16 +63,18 @@ def sparse_assign(values: jax.Array, indices: jax.Array, centers: jax.Array, mod
 
 # The spmm kernels tile BOTH grid axes (row blocks × column blocks —
 # kernels/spmm.py), so their VMEM footprint is bounded by plan_tiles against
-# this budget at ANY p: the old "fall back to jnp past ~2^15" ceiling is gone.
-# The budget is defined once in kernels/spmm.py (the tile planner's input) and
-# re-exported here so the dispatch gate and the planner can never disagree;
-# "kernel" only demotes to "ref" in the pathological corner where even the
-# minimum (8, 256) tile exceeds it (an extremely wide l).
+# this budget at ANY p. The budget is the tile planner's own input
+# (spmm.SPMM_VMEM_BUDGET, the package's one VMEM budget) so the dispatch
+# gate and the planner can never disagree. The corners the kernels cannot serve — even the
+# minimum (8, 256) tile over the budget (an extremely wide l), or m too wide
+# for an 8-row SMEM index window — raise under an explicit "kernel" and fall
+# to "ref" under "auto", where the dispatch counter shows it.
 _SPMM_VMEM_BUDGET = _spmm.SPMM_VMEM_BUDGET
 
 
 def _sparse_mode(mode: str, p: int, ell: int,
-                 value_dtype=jnp.float32, dense_dtype=jnp.float32) -> str:
+                 value_dtype=jnp.float32, dense_dtype=jnp.float32,
+                 m: int | None = None) -> str:
     """Normalize a backend name to this module's vocabulary.
 
     Call sites forward ``Plan.impl`` / ``StreamEngine.impl`` here verbatim, and
@@ -78,21 +84,34 @@ def _sparse_mode(mode: str, p: int, ell: int,
     uses the ONE tile model (spmm.plan_tiles / tile_vmem_bytes) at the actual
     operand dtypes — no second, disagreeing footprint estimate lives here.
     """
+    explicit = mode == "kernel"
     if mode == "auto":
         mode = "kernel" if _on_tpu() else "ref"
     if mode not in ("kernel", "interpret"):
         return "ref"
     if mode == "interpret":  # host interpreter: no VMEM constraint to respect
         return mode
-    br, pb = _spmm.plan_tiles(p, ell, value_dtype, dense_dtype)
+    try:
+        br, pb = _spmm.plan_tiles(p, ell, value_dtype, dense_dtype, m=m)
+    except ValueError:
+        if explicit:
+            raise
+        return "ref"
     vmem = _spmm.tile_vmem_bytes(p, ell, value_dtype, dense_dtype, br, pb)
-    return "kernel" if vmem <= _SPMM_VMEM_BUDGET else "ref"
+    if vmem <= _SPMM_VMEM_BUDGET:
+        return "kernel"
+    if explicit:
+        raise ValueError(
+            f"spmm kernel cannot tile p={p}, l={ell} within the "
+            f"{_SPMM_VMEM_BUDGET}-byte VMEM budget ({vmem} bytes at the "
+            f"smallest tile); use mode='ref'")
+    return "ref"
 
 
 def spmm(values: jax.Array, indices: jax.Array, dense: jax.Array,
          mode: str = "auto") -> jax.Array:
     """T (n, l) = W @ dense for compact sparse rows (the low-rank projection)."""
-    mode = _sparse_mode(mode, *dense.shape, values.dtype, dense.dtype)
+    mode = _sparse_mode(mode, *dense.shape, values.dtype, dense.dtype, m=values.shape[1])
     _count_dispatch("spmm", mode)
     if mode == "ref":
         return _ref.ref_spmm(values, indices, dense)
@@ -102,7 +121,7 @@ def spmm(values: jax.Array, indices: jax.Array, dense: jax.Array,
 def spmm_t(values: jax.Array, indices: jax.Array, t: jax.Array, p: int,
            mode: str = "auto") -> jax.Array:
     """Y (p, l) = Wᵀ @ t — scatter sparse rows into the l-dim sketch."""
-    mode = _sparse_mode(mode, p, t.shape[1], values.dtype, t.dtype)
+    mode = _sparse_mode(mode, p, t.shape[1], values.dtype, t.dtype, m=values.shape[1])
     _count_dispatch("spmm_t", mode)
     if mode == "ref":
         return _ref.ref_spmm_t(values, indices, t, p)
@@ -116,7 +135,7 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
 
     Above the fused kernel's single-tile ceiling (p > 2^15) the kernel modes
     compose the chunked FWHT with an XLA gather — still the kernel FWHT path,
-    just not single-pass.
+    just not single-pass (counted as path="kernel_chunked").
     """
     if mode == "auto":
         mode = "kernel" if _on_tpu() else "ref"
@@ -126,7 +145,7 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
             return _sf.sketch_fused(x, signs, indices,
                                     interpret=(mode == "interpret"))
         _count_dispatch("sketch_fused", f"{mode}_chunked")
-        y = _fwht.hd_precondition(x, signs, interpret=(mode == "interpret"))
+        y = hd_precondition(x, signs, mode=mode)
         return jnp.take_along_axis(y, indices, axis=-1)
     _count_dispatch("sketch_fused", mode)
     return _ref.ref_sketch_fused(x, signs, indices)

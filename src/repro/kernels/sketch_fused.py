@@ -7,9 +7,11 @@ dense intermediate in VMEM and writes ONLY the m kept values per row — HBM
 traffic drops from (2 + γ)·n·p·4 bytes to (1 + 2γ)·n·p·4, i.e. ~2.5× for
 γ = 0.05 on the streaming-ingest path (the paper's Tables III/IV setting).
 
-The per-row gather uses the indices as a VMEM scalar walk (rolled loop, same
-pattern as sparse_assign's densify but in reverse); the FWHT itself stays on
-the MXU via the Kronecker form.
+The FWHT stays on the MXU via the Kronecker form (kernels.fwht.hd_tile); the
+per-row gather walks the indices as SMEM scalars and reads each kept value
+out of its aligned VMEM tile (kernels.walk). Every power of two p from 2^8 to
+2^15 compiles for v5e; above that kernels.ops composes the chunked FWHT with
+an XLA gather.
 """
 from __future__ import annotations
 
@@ -18,9 +20,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ros import hadamard_matrix
-from repro.kernels.fwht import MAX_P_SINGLE, default_block_rows, factor_p
+from repro.kernels import walk
+from repro.kernels.fwht import MAX_P_SINGLE, default_block_rows, factor_p, hd_tile
 
 # The fused kernel holds a whole (block_rows, p) preconditioned tile in VMEM,
 # so it shares the single-tile FWHT ceiling; above it, kernels.ops composes
@@ -28,28 +32,21 @@ from repro.kernels.fwht import MAX_P_SINGLE, default_block_rows, factor_p
 MAX_P_FUSED = MAX_P_SINGLE
 
 
-def _kernel(x_ref, d_ref, ha_ref, hb_ref, idx_ref, out_ref, *, a: int, b: int, m: int):
-    x = x_ref[...] * d_ref[...]
-    bn = x.shape[0]
-    f32 = jnp.float32
-    if a == 1:
-        y = jax.lax.dot(x, hb_ref[...], preferred_element_type=f32)
-    else:
-        y = jax.lax.dot(x.reshape(bn * a, b), hb_ref[...], preferred_element_type=f32)
-        y = y.reshape(bn, a, b).transpose(0, 2, 1).reshape(bn * b, a)
-        y = jax.lax.dot(y, ha_ref[...], preferred_element_type=f32)
-        y = y.reshape(bn, b, a).transpose(0, 2, 1).reshape(bn, a * b)
-    y = y.astype(out_ref.dtype)
+def _kernel(x_ref, d_ref, ha_ref, hb_ref, idx_ref, out_ref, y_ref, *,
+            a: int, b: int, m: int):
+    bn, p = x_ref.shape
+    y_ref[:, :p] = hd_tile(x_ref[...], d_ref, ha_ref, hb_ref, a=a, b=b)
+    walk.gather(out_ref, y_ref, idx_ref, bn=bn, m=m)
 
-    def body(t, _):
-        i = t // m
-        j = t % m
-        col = idx_ref[i, j]
-        pl.store(out_ref, (i, pl.dslice(j, 1)),
-                 jax.lax.dynamic_slice(y, (i, col), (1, 1))[0])
-        return 0
 
-    jax.lax.fori_loop(0, bn * m, body, 0)
+def fused_block_rows(p: int, m: int, dtype=jnp.float32) -> int:
+    """Rows per tile: the FWHT tile model, minus its (rows, p) output block,
+    plus the f32 copy the gather reads and the double-buffered (rows, m)
+    f32 output block; capped by the SMEM index window."""
+    extra = (walk.pad_lanes(p) * 4 + 2 * walk.pad_lanes(m) * 4
+             - 2 * p * jnp.dtype(dtype).itemsize)
+    return min(default_block_rows(p, dtype, extra_bytes_per_row=extra),
+               walk.smem_block_rows(m, n_windows=1))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -59,16 +56,16 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
 
     x (n, p) with p a power of two ≤ MAX_P_FUSED; indices (n, m) int32
     (sorted, distinct). Dispatch through kernels.ops.sketch_fused to get the
-    composed chunked-FWHT + gather fallback above the ceiling.
+    composed chunked-FWHT + gather path above the ceiling.
     """
     n, p = x.shape
     m = indices.shape[1]
     if p > MAX_P_FUSED:
         raise ValueError(
             f"p={p} exceeds the fused kernel's single-tile ceiling "
-            f"{MAX_P_FUSED}; use kernels.ops.sketch_fused (composed fallback)")
+            f"{MAX_P_FUSED}; use kernels.ops.sketch_fused (composed path)")
     a, b = factor_p(p)
-    br = block_rows or default_block_rows(p, x.dtype)
+    br = block_rows or fused_block_rows(p, m, x.dtype)
     n_pad = -n % br
     if n_pad:
         x = jnp.pad(x, ((0, n_pad), (0, 0)))
@@ -76,6 +73,7 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
     ha = hadamard_matrix(a, x.dtype) if a > 1 else jnp.zeros((1, 1), x.dtype)
     hb = hadamard_matrix(b, x.dtype)
     d2 = signs.astype(x.dtype)[None, :]
+    mp = walk.pad_lanes(m)
 
     out = pl.pallas_call(
         functools.partial(_kernel, a=a, b=b, m=m),
@@ -85,10 +83,11 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
             pl.BlockSpec((1, p), lambda i: (0, 0)),
             pl.BlockSpec((max(a, 1), max(a, 1)), lambda i: (0, 0)),
             pl.BlockSpec((b, b), lambda i: (0, 0)),
-            pl.BlockSpec((br, m), lambda i: (i, 0)),
+            walk.smem_spec(br, m, lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((br, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n + n_pad, m), x.dtype),
+        out_specs=pl.BlockSpec((br, mp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n + n_pad, mp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((br, walk.pad_lanes(p)), jnp.float32)],
         interpret=interpret,
-    )(x, d2, ha, hb, indices)
-    return out[:n] if n_pad else out
+    )(x, d2, ha, hb, indices.astype(jnp.int32))
+    return out[:n, :m].astype(x.dtype)

@@ -22,11 +22,15 @@ Without ``--process-id`` the command is the COORDINATOR: it picks a free port
 and spawns ``--nproc`` copies of itself as workers (the single-machine path;
 on a real cluster start one worker per host with ``--process-id``/
 ``--coordinator`` set explicitly and skip the self-spawn).
+
+The self-spawn is a CPU multi-process rehearsal: it starts N processes on one
+host, and a TPU belongs to one process at a time, so do not run it on a chip
+host. Run it with ``JAX_PLATFORMS=cpu``; the workers inherit the caller's
+platform.
 """
 from __future__ import annotations
 
 import argparse
-import os
 import socket
 import subprocess
 import sys
@@ -75,8 +79,7 @@ def _spawn(args) -> int:
         cmd += ["--ckpt-dir", args.ckpt_dir]
     if args.resume:
         cmd += ["--resume"]
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
-    procs = [subprocess.Popen(cmd + ["--process-id", str(pid)], env=env)
+    procs = [subprocess.Popen(cmd + ["--process-id", str(pid)])
              for pid in range(args.nproc)]
     rc = 0
     for p in procs:
@@ -86,7 +89,9 @@ def _spawn(args) -> int:
 
 def _worker(args) -> int:
     from repro import cluster
+    from repro.launch import compile_cache
 
+    compile_cache.enable()
     cluster.initialize(args.coordinator, args.nproc, args.process_id)
 
     import jax

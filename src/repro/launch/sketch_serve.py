@@ -140,6 +140,9 @@ def main(argv=None):
     from repro.api import Plan
     from repro.sketchserve import (SketchService, SnapshotPolicy,
                                    restore_service, serve_http)
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     # deterministic mode: crash/resume parity needs per-request seeding,
     # serialized folds (fold boundaries = request boundaries), and the host

@@ -57,7 +57,10 @@ def main(argv=None):
 
     from repro import api
     from repro.data.pipeline import VectorStreamSource
+    from repro.launch import compile_cache
     from repro.stream import StreamKMeansConfig
+
+    compile_cache.enable()
 
     backend = "stream"
     if args.shards > 1:

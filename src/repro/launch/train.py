@@ -45,12 +45,15 @@ def main(argv=None):
     from repro.configs.registry import get_arch
     from repro.core.grad_compress import CompressConfig
     from repro.data.pipeline import SyntheticLMSource
+    from repro.launch import compile_cache
     from repro.launch.mesh import make_host_mesh, make_production_mesh
     from repro.models.api import get_api
     from repro.train import checkpoint
     from repro.train.optimizer import OptConfig
     from repro.train.trainer import (TrainerConfig, abstract_state, init_state,
                                      make_dist, make_train_fn, state_shardings)
+
+    compile_cache.enable()
 
     cfg = get_arch(args.arch, reduced=args.reduced)
     api = get_api(cfg)

@@ -129,10 +129,14 @@ def range_finalize(state: RangeState, m: int, omega_mat: jax.Array,
     if not 0 < r <= ell:
         raise ValueError(f"rank must be in [1, l={ell}], got {r}")
     corr = (p - m) / (p - 1)
-    # the debiased operator's sketch, exactly: (S − corr·diag(d))·Omega, scaled
-    # by 1/count so the solve below is conditioned like Ĉ_n, not n·Ĉ_n
-    yp = (state.y - corr * state.diag[:, None] * omega_mat) / state.count
-    u, _, _ = jnp.linalg.svd(yp, full_matrices=False)
-    q = u[:, :r]                                             # (p, r) basis
-    core = (q.T @ yp) @ jnp.linalg.pinv(q.T @ omega_mat)     # r×l fat solve
-    return eig_in_basis(q, _cov_scale(p, m) * core)
+    # Full f32 products: a TPU's default runs an f32 matmul as one bf16 pass,
+    # which left the eigenmodel ~3e-4 (eigenvalues) and ~2e-3 (subspace) off
+    # between two states that agreed to 6e-8. One finalize per fit, O(p·l²).
+    with jax.default_matmul_precision("highest"):
+        # the debiased operator's sketch, exactly: (S − corr·diag(d))·Omega,
+        # scaled by 1/count so the solve below is conditioned like Ĉ_n
+        yp = (state.y - corr * state.diag[:, None] * omega_mat) / state.count
+        u, _, _ = jnp.linalg.svd(yp, full_matrices=False)
+        q = u[:, :r]                                         # (p, r) basis
+        core = (q.T @ yp) @ jnp.linalg.pinv(q.T @ omega_mat)  # r×l fat solve
+        return eig_in_basis(q, _cov_scale(p, m) * core)

@@ -112,7 +112,7 @@ def moe_apply_ep(params: dict, x: jax.Array, k: int, capacity_factor: float,
     """Distributed MoE: x (B, S, d) with B sharded over ``dp_axes`` and S over
     ``ep_axis``; experts sharded over ``ep_axis``. Two all_to_alls per layer."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_ep = mesh.shape[ep_axis]
     e = params["router"].shape[1]
@@ -205,6 +205,6 @@ def moe_apply_ep(params: dict, x: jax.Array, k: int, capacity_factor: float,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(*args)

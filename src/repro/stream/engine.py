@@ -38,7 +38,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -375,10 +375,12 @@ class StreamEngine:
                 cnt = jax.lax.psum(acc.kmeans_reassigned(new.kmeans, s, a0), axis)
                 return with_counts(new, cnt)
 
+        # check_vma=False: the Pallas kernels inside declare no per-axis
+        # varying type for their outputs
         return shard_map(
             sharded_update, mesh=self.mesh,
             in_specs=(state_spec, P(axis), state_spec),
-            out_specs=state_spec,
+            out_specs=state_spec, check_vma=False,
         )
 
     # ------------------------------------------------------------- running --
@@ -649,6 +651,7 @@ class StreamEngine:
         return shard_map(
             sharded_update, mesh=self.mesh,
             in_specs=(P(), P(axis), P(), P(), P(), P()), out_specs=P(),
+            check_vma=False,   # Pallas kernels inside, as in the update above
         )
 
     def _init_refine_carry(self):

@@ -20,7 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.sampling import SparseRows
@@ -84,9 +84,11 @@ def _lowrank_fn(mesh, axes, p, ell, impl):
         return delta
 
     row_spec = P(axes if len(axes) > 1 else axes[0], None)
+    # check_vma=False: the spmm kernels inside declare no per-axis varying
+    # type for their outputs
     return jax.jit(shard_map(local, mesh=mesh,
                              in_specs=(row_spec, row_spec, P()),
-                             out_specs=P()))
+                             out_specs=P(), check_vma=False))
 
 
 def sharded_lowrank(s: SparseRows, omega_mat: jax.Array, mesh, axes=("data",),
@@ -226,7 +228,7 @@ def sketch_sharded(x: jax.Array, spec, mesh, axes=("data",)) -> SparseRows:
     from repro.core import sketch
 
     xs = shard_rows(x, mesh, axes)
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         return sketch.sketch(xs, spec)
 
 
@@ -236,7 +238,7 @@ def sharded_kmeans(s: SparseRows, k: int, key, mesh, n_init: int = 3,
     center/count scatter-adds psum over the data axes)."""
     from repro.core import kmeans
 
-    with mesh:
+    with jax.set_mesh(mesh):
         return kmeans.sparse_kmeans_core(
             s.values, s.indices, s.p, k, key, n_init=n_init, max_iter=max_iter,
             tol=tol)

@@ -119,7 +119,7 @@ def test_perworker_grad_estimator_matches_reference():
     with exactly the same per-worker masks."""
     run_script("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_host_mesh
         from repro.core import ros

@@ -150,6 +150,26 @@ def test_sparse_mode_vocabulary_and_interpret():
     assert ops._sparse_mode("interpret", 1 << 20, 128) == "interpret"
 
 
+def test_sparse_mode_never_demotes_an_explicit_kernel(monkeypatch):
+    """A shape no tile can serve (l too wide for the VMEM budget, or m too
+    wide for an 8-row SMEM index window): an explicit "kernel" raises, and
+    "auto" on a TPU host falls to "ref", which the dispatch counter shows."""
+    from repro import obs
+
+    too_wide_l = (1 << 16, 1 << 14, None)
+    too_wide_m = (1 << 16, 128, 1 << 14)
+    for p, ell, m in (too_wide_l, too_wide_m):
+        with pytest.raises(ValueError):
+            ops._sparse_mode("kernel", p, ell, m=m)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    reg = obs.MetricsRegistry()
+    monkeypatch.setattr(obs.registry, "_default", reg)
+    values = jnp.ones((8, too_wide_m[2]))
+    indices = jnp.zeros((8, too_wide_m[2]), jnp.int32)
+    ops.spmm(values, indices, jnp.ones((too_wide_m[0], 128)))
+    assert reg.counter("kernels.dispatch", op="spmm", path="ref").value == 1
+
+
 def test_plan_tiles_respects_budget_and_alignment():
     """plan_tiles output is a pow2 column block ≥ 256 (lane-aligned) whose
     footprint fits the budget, at representative (p, l, dtype) corners."""

@@ -121,13 +121,20 @@ def test_two_pass_kmeans_bit_identical_and_tracked():
                                   np.asarray(est.centers_))
 
 
-def test_two_pass_kmeans_beats_streaming_centers():
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_two_pass_kmeans_beats_streaming_centers(seed):
     """The refinement target: consistent-assignment rebuilds move the centers
     closer to the true cluster means than the one-pass streaming fold, whose
-    centers inherit assignment noise from the evolving first pass."""
+    centers inherit assignment noise from the evolving first pass.
+
+    The clusters overlap (sep 0.5 at unit noise), so the first pass does
+    misassign rows. Well-separated clusters (sep 3) have no assignment noise
+    to remove: there both fits land equally close to the truth, and which
+    one wins is a coin toss over the draws."""
     from scipy.optimize import linear_sum_assignment
 
-    x, _, centers = make_clusters(KEY, n=4000, p=32, k=5, sep=3.0, noise=1.0)
+    x, _, centers = make_clusters(jax.random.fold_in(KEY, seed), n=4000, p=32,
+                                  k=5, sep=0.5, noise=1.0)
     plan = Plan(backend="stream", gamma=0.5, batch_size=100)
 
     def dist_to_truth(est):
