@@ -1,6 +1,7 @@
 """Telemetry overhead + reconciliation benchmark → ``BENCH_obs.json``.
 
-Three claims of the observability layer (repro.obs), each measured and gated:
+Three claims of the observability layer (repro.obs), each measured and gated,
+and the cost of one span (recorded, not gated):
 
 1. **Zero-cost disabled** — an engine run with ``telemetry=None`` (the
    default) vs the pre-obs loop shape: the telemetry branch is one
@@ -16,6 +17,11 @@ Three claims of the observability layer (repro.obs), each measured and gated:
    count) reconciles EXACTLY with the known request totals — metrics that
    drift from the truth are worse than no metrics.
 
+The span cost is timed with no profiler running, as the ingest path runs
+outside a traced window: a bare ``jax.profiler.TraceAnnotation``, and an
+``obs.span`` with no registry nested under a call span, with no attribute
+and with the four of a chunk span.
+
 CI runs this as the ``obs-bench`` job and uploads both artifacts so the
 overhead trajectory accumulates across commits.
 """
@@ -25,6 +31,7 @@ import json
 import os
 import sys
 import time
+import timeit
 
 import jax
 import numpy as np
@@ -105,6 +112,33 @@ def engine_overhead(jsonl_path: str) -> None:
         f"off={t_off:.4f}s on={t_on:.4f}s")
 
 
+# ------------------------------------------------------------ span cost -----
+
+
+def span_cost(number: int = 100_000, repeat: int = 15) -> None:
+    from jax.profiler import TraceAnnotation
+
+    def best(fn) -> float:
+        return min(timeit.repeat(fn, number=number, repeat=repeat)) / number * 1e6
+
+    def bare():
+        with TraceAnnotation("ingest.partial_fit.chunk"):
+            pass
+
+    def plain():
+        with obs.span("sketch"):
+            pass
+
+    def chunk():
+        with obs.span("chunk", chunk=5, step=5, shard=0, rows=4096):
+            pass
+
+    record("obs/span/bare_annotation", best(bare))
+    with obs.span("ingest.partial_fit", call=0, rows=8192):
+        record("obs/span/nested_no_attrs", best(plain))
+        record("obs/span/nested_chunk_attrs", best(chunk))
+
+
 # ------------------------------------------- 256-tenant exact reconcile -----
 
 
@@ -183,6 +217,7 @@ def run(json_path: str = "BENCH_obs.json"):
     RECORDS.clear()
     jsonl = os.environ.get("OBS_SMOKE_JSONL", "obs_smoke.jsonl")
     engine_overhead(jsonl)
+    span_cost()
     serve_reconcile()
     out = os.environ.get("BENCH_OBS_JSON", json_path)
     with open(out, "w") as f:

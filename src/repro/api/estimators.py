@@ -27,12 +27,14 @@ come back in the ORIGINAL domain (eigenvectors / means / centers unmixed by
 from __future__ import annotations
 
 import functools
+import itertools
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.plan import BACKENDS, Plan
 from repro.core import estimators as est
 from repro.core import ros
@@ -105,7 +107,7 @@ def _reduce_batch(r: "_MomentReducer"):
 @_moment_backend("stream")
 def _reduce_stream(r: "_MomentReducer"):
     st = r.state
-    if int(st.count) == 0:
+    if _read_count(st) == 0:
         raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
     cov = acc.moment_finalize_cov(st, r.spec.m) if r.track_cov else None
     return acc.moment_finalize_mean(st, r.spec.m), cov, st.count
@@ -115,13 +117,19 @@ def _reduce_stream(r: "_MomentReducer"):
 def _reduce_sharded(r: "_MomentReducer"):
     r.flush_step()  # a trailing partial step still needs its psum
     st = r.state
-    if int(st.count) == 0:
+    if _read_count(st) == 0:
         raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
     cov = acc.moment_finalize_cov(st, r.spec.m) if r.track_cov else None
     return acc.moment_finalize_mean(st, r.spec.m), cov, st.count
 
 
 assert set(MOMENT_BACKENDS) == set(BACKENDS), "registry out of sync with Plan.BACKENDS"
+
+
+def _read_count(state) -> int:
+    """The folded row count, read back to the host (waits for the device)."""
+    with obs.span("readback", site="count"):
+        return int(state.count)
 
 
 class _MomentReducer:
@@ -241,8 +249,9 @@ class _MomentReducer:
                               s.p)
         from repro import cluster
 
-        vals = np.concatenate([np.asarray(s.values) for s in self._step_parts])
-        idxs = np.concatenate([np.asarray(s.indices) for s in self._step_parts])
+        with obs.span("readback", site="assemble"):
+            vals = np.concatenate([np.asarray(s.values) for s in self._step_parts])
+            idxs = np.concatenate([np.asarray(s.indices) for s in self._step_parts])
         return SparseRows(cluster.global_rows(vals, self._mesh, self.plan.axis),
                           cluster.global_rows(idxs, self._mesh, self.plan.axis),
                           self.spec.p_pad)
@@ -263,7 +272,7 @@ class _MomentReducer:
         differ only in HOW the same linear deltas were reduced)."""
         self.flush_step()  # a trailing partial step still needs its psum
         st = self.state
-        if int(st.count) == 0:
+        if _read_count(st) == 0:
             raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
         if self.plan.lowrank_method == "range":
             return (lowrank_mod.range_finalize_mean(st, self.spec.m),
@@ -424,6 +433,7 @@ class SketchCursor:
         self.consumers: list["SketchedEstimator"] = []
         self.scan = False        # opt-in lax.scan hot loop for partial_fit
         self._scan_out = None    # last scan's carries — the sync() barrier
+        self._calls = itertools.count()  # the ``call`` of each ingest call's spans
 
     def register(self, consumer: "SketchedEstimator") -> None:
         self.consumers.append(consumer)
@@ -446,29 +456,36 @@ class SketchCursor:
         """Sketch one ≤batch_size chunk under its (step, shard) mask key and
         hand the SAME SparseRows to every consumer."""
         step, shard = self.plan.step_shard(self.chunk)
-        s = sketch_mod.sketch(rows, self.spec,
-                              batch_key=batch_key(self.spec, step, shard),
-                              impl=self.plan.impl)
-        self.n_sketches += 1
-        self.last_sketch = s
         n = int(rows.shape[0])
-        for c in self.consumers:
-            c._consume(s, step, shard, n)
-        self.chunk += 1
-        self.count += n
-        self.chunk_rows.append(n)
+        with obs.span("chunk", chunk=self.chunk, step=step, shard=shard, rows=n):
+            with obs.span("sketch"):
+                s = sketch_mod.sketch(rows, self.spec,
+                                      batch_key=batch_key(self.spec, step, shard),
+                                      impl=self.plan.impl)
+            self.n_sketches += 1
+            self.last_sketch = s
+            for i, c in enumerate(self.consumers):
+                with obs.span("fold." + c.kind, consumer=i):
+                    c._consume(s, step, shard, n)
+            self.chunk += 1
+            self.count += n
+            self.chunk_rows.append(n)
 
     def partial_fit(self, x) -> None:
-        x = jnp.asarray(x)
-        if x.ndim != 2:
-            raise ValueError(f"expected (rows, p) data, got shape {x.shape}")
-        x = x.astype(self.plan.dtype)
-        with self._lock:  # concurrent producers serialize whole-call (see class doc)
-            self.ensure_spec(x.shape[1])
-            start = self._fold_rows_scanned(x) if self.scan else 0
-            bs = self.plan.batch_size
-            for i in range(start, x.shape[0], bs):
-                self.fold_rows(x[i:i + bs])
+        shape = np.shape(x)
+        with obs.span("ingest.partial_fit", call=next(self._calls),
+                      rows=shape[0] if shape else 0):
+            with obs.span("h2d", bytes=getattr(x, "nbytes", 0)):
+                x = jnp.asarray(x)
+                if x.ndim != 2:
+                    raise ValueError(f"expected (rows, p) data, got shape {x.shape}")
+                x = x.astype(self.plan.dtype)
+            with self._lock:  # concurrent producers serialize whole-call (see class doc)
+                self.ensure_spec(x.shape[1])
+                start = self._fold_rows_scanned(x) if self.scan else 0
+                bs = self.plan.batch_size
+                for i in range(start, x.shape[0], bs):
+                    self.fold_rows(x[i:i + bs])
 
     def scan_descs(self) -> tuple | None:
         """The consumers' in-scan fold descriptors, or None if any consumer
@@ -501,18 +518,19 @@ class SketchCursor:
                 "(scan=False) for those, or switch to stream/minibatch/"
                 "lowrank folds")
         take = steps * ns * bs
-        xs = x[:take].reshape(steps, ns, bs, x.shape[1])
-        step0 = self.chunk // ns
-        for c in self.consumers:
-            c._scan_prepare(self, xs, step0)
-        scan_fn = _build_scan_fn(plan, spec.p, spec.m, spec.transform,
-                                 ros.resolve_impl(plan.impl), descs)
-        carries = tuple(c._scan_carry() for c in self.consumers)
-        auxes = tuple(c._scan_aux() for c in self.consumers)
-        new_carries, ys = scan_fn(carries, auxes, xs, jnp.int32(step0),
-                                  spec.signs_key(), spec.mask_key())
-        for c, nc, y in zip(self.consumers, new_carries, ys):
-            c._scan_absorb(nc, y, steps, ns * bs)
+        with obs.span("scan", steps=steps):
+            xs = x[:take].reshape(steps, ns, bs, x.shape[1])
+            step0 = self.chunk // ns
+            for c in self.consumers:
+                c._scan_prepare(self, xs, step0)
+            scan_fn = _build_scan_fn(plan, spec.p, spec.m, spec.transform,
+                                     ros.resolve_impl(plan.impl), descs)
+            carries = tuple(c._scan_carry() for c in self.consumers)
+            auxes = tuple(c._scan_aux() for c in self.consumers)
+            new_carries, ys = scan_fn(carries, auxes, xs, jnp.int32(step0),
+                                      spec.signs_key(), spec.mask_key())
+            for c, nc, y in zip(self.consumers, new_carries, ys):
+                c._scan_absorb(nc, y, steps, ns * bs)
         self.chunk += steps * ns
         self.count += take
         self.chunk_rows.extend([bs] * (steps * ns))
@@ -522,14 +540,14 @@ class SketchCursor:
         return take
 
     def sync(self) -> None:
-        """Block until the last folded chunk's sketch is materialized — the
-        public ingest barrier (benchmarks time ingest against this, not
-        against private reducer state). After a scanned fold the barrier is
-        the scan's output carries (no per-chunk sketch ever materializes)."""
-        if self.last_sketch is not None:
-            jax.block_until_ready((self.last_sketch.values, self.last_sketch.indices))
-        if self._scan_out is not None:
-            jax.block_until_ready(self._scan_out)
+        """Block until everything folded so far is materialized: the last
+        chunk's sketch (after a scanned fold, the scan's output carries) and
+        every registered consumer's fold state — the public ingest barrier
+        to time a fold pass against."""
+        last = self.last_sketch
+        jax.block_until_ready((
+            None if last is None else (last.values, last.indices),
+            self._scan_out, [c._fold_state() for c in self.consumers]))
 
     def fold_source(self, source, steps: int, seed: int | None = None) -> None:
         """One pass over a normalized ``(seed, step, shard) → (b, p)`` source
@@ -541,15 +559,22 @@ class SketchCursor:
         contract makes "distribute the stream" exactly that); the per-step
         shard_map reduction then psums across hosts.
         """
-        with self._lock:  # concurrent producers serialize whole-call (see class doc)
+        with (obs.span("ingest.fold_source", call=next(self._calls), steps=steps),
+              self._lock):  # concurrent producers serialize whole-call (see class doc)
             if _is_multiprocess() and self.plan.backend == "sharded":
                 self._fold_source_multiprocess(source, steps, seed)
                 return
             for step in range(steps):
                 for shard in range(self.plan.n_shards):
-                    rows = jnp.asarray(source(seed, step, shard)).astype(self.plan.dtype)
+                    rows = self._source_rows(source, seed, step, shard)
                     self.ensure_spec(rows.shape[1])
                     self.fold_rows(rows)
+
+    def _source_rows(self, source, seed, step: int, shard: int) -> jax.Array:
+        """Batch (step, shard) of ``source``, on the device in the plan's dtype."""
+        rows = source(seed, step, shard)
+        with obs.span("h2d", bytes=getattr(rows, "nbytes", 0)):
+            return jnp.asarray(rows).astype(self.plan.dtype)
 
     def _fold_source_multiprocess(self, source, steps: int,
                                   seed: int | None) -> None:
@@ -578,7 +603,7 @@ class SketchCursor:
         for c in self.consumers:
             if c._needs_first_sketch():
                 if rows0 is None:
-                    rows0 = jnp.asarray(source(seed, 0, 0)).astype(self.plan.dtype)
+                    rows0 = self._source_rows(source, seed, 0, 0)
                     self.ensure_spec(rows0.shape[1])
                     s0 = sketch_mod.sketch(
                         rows0, self.spec, batch_key=batch_key(self.spec, 0, 0),
@@ -587,7 +612,7 @@ class SketchCursor:
         for step in range(steps):
             for shard in range(self.plan.n_shards):
                 if shard in mine:
-                    rows = jnp.asarray(source(seed, step, shard)).astype(self.plan.dtype)
+                    rows = self._source_rows(source, seed, step, shard)
                     self.ensure_spec(rows.shape[1])
                     self.fold_rows(rows)
                 else:
@@ -596,8 +621,9 @@ class SketchCursor:
                     # rows-per-chunk is unknown here (0 = not locally held).
                     self.chunk += 1
                     self.chunk_rows.append(0)
-            for c in self.consumers:
-                c._step_flush()
+            for i, c in enumerate(self.consumers):
+                with obs.span("fold." + c.kind, consumer=i):
+                    c._step_flush()
 
 
 # -------------------------------------------------------------- base class --
@@ -618,6 +644,7 @@ class SketchedEstimator:
     the fitted attributes and returns self.
     """
 
+    kind = "estimator"     # names the consumer's spans: fold.<kind>, finalize.<kind>
     _track_cov = False
     _keep_sketch = False
     _needs_moments = True  # False when _finalize never calls reducer.reduce()
@@ -665,14 +692,20 @@ class SketchedEstimator:
         return self
 
     def sync(self) -> "SketchedEstimator":
-        """Block until this estimator's ingest (its cursor's last sketch) is
-        materialized — for wall-clock measurements of the fold pass."""
+        """Block until this estimator's ingest (its cursor's last sketch and
+        its consumers' fold states) is materialized — for wall-clock
+        measurements of the fold pass."""
         self._cursor.sync()
         return self
 
     def _consume(self, s: SparseRows, step: int, shard: int, n_rows: int) -> None:
         self._fold_sketch(s, step, shard)
         self.count_ += n_rows
+
+    def _fold_state(self):
+        """The device arrays the folds so far write (what sync() waits on)."""
+        r = self._reducer
+        return None if r is None else (r.state, r.parts, r._step_parts)
 
     def _fold_sketch(self, s: SparseRows, step: int, shard: int) -> None:
         self._reducer.fold(s, step, shard)
@@ -770,7 +803,8 @@ class SketchedEstimator:
     def finalize(self) -> "SketchedEstimator":
         if self.spec_ is None:
             raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
-        self._finalize()
+        with obs.span("finalize." + self.kind):
+            self._finalize()
         self._fitted = True
         return self
 
@@ -1027,6 +1061,7 @@ class SparsifiedMean(SketchedEstimator):
     preconditioned domain), ``count_``.
     """
 
+    kind = "mean"
     _track_cov = False
 
     def _finalize(self) -> None:
@@ -1044,6 +1079,7 @@ class SparsifiedCov(SketchedEstimator):
     ``count_``. Use :meth:`cov_original` for the (p, p) original-domain matrix.
     """
 
+    kind = "cov"
     _track_cov = True
 
     def _on_spec(self, spec: sketch_mod.SketchSpec) -> None:
@@ -1083,6 +1119,7 @@ class SparsifiedPCA(SketchedEstimator):
     ``cov_lowrank_`` (:class:`repro.lowrank.LowRankCov` | None).
     """
 
+    kind = "pca"
     _track_cov = True
 
     def __init__(self, n_components: int, plan: Plan, key: jax.Array | int = 0):
@@ -1237,6 +1274,7 @@ class SparsifiedKMeans(SketchedEstimator):
     ``reassign_counts_`` / ``reassign_fraction_`` ((steps,) arrays; minibatch).
     """
 
+    kind = "kmeans"
     _track_cov = False
     _needs_moments = False  # centers come from the solver, not Thm-4/6
 
@@ -1281,9 +1319,10 @@ class SparsifiedKMeans(SketchedEstimator):
             self._reducer.fold(s, step, shard)
             return
         if self._km_state is None:
-            self._km_state = acc.kmeans_init(
-                fold_in_str(self.spec_.key, "api-kmeans"), s, self.k, self.n_init,
-                decay=self.decay)
+            with obs.span("init"):
+                self._km_state = acc.kmeans_init(
+                    fold_in_str(self.spec_.key, "api-kmeans"), s, self.k,
+                    self.n_init, decay=self.decay)
         if self.plan.backend == "sharded":
             # mesh-resident fold: buffer the step's shard sketches and reduce
             # them in-mesh at the flush — assignment stays on-device per
@@ -1307,15 +1346,16 @@ class SparsifiedKMeans(SketchedEstimator):
 
     def _flush_step(self) -> None:
         if self._km_step_parts:
-            old_count = int(self._km_state.count)
+            old_count = _read_count(self._km_state)
             mesh = _sharded_mesh(self.plan)
             parts, self._km_step_parts = self._km_step_parts, []
             mask = None
             if _is_multiprocess():
                 from repro import cluster
 
-                vals = np.concatenate([np.asarray(s.values) for s in parts])
-                idxs = np.concatenate([np.asarray(s.indices) for s in parts])
+                with obs.span("readback", site="assemble"):
+                    vals = np.concatenate([np.asarray(s.values) for s in parts])
+                    idxs = np.concatenate([np.asarray(s.indices) for s in parts])
                 s_cat = SparseRows(
                     cluster.global_rows(vals, mesh, self.plan.axis),
                     cluster.global_rows(idxs, mesh, self.plan.axis),
@@ -1330,8 +1370,9 @@ class SparsifiedKMeans(SketchedEstimator):
                 track_reassignments=self.track_reassignments, mask=mask)
             self._km_state = new
             if self.track_reassignments:
-                rows = int(new.count) - old_count
-                self._reassign_history.append((np.asarray(cnt), rows))
+                rows = _read_count(new) - old_count
+                with obs.span("readback", site="reassign_counts"):
+                    self._reassign_history.append((np.asarray(cnt), rows))
             return
         if self._km_pending is None:
             return
@@ -1344,7 +1385,8 @@ class SparsifiedKMeans(SketchedEstimator):
             for s, a0 in self._km_step_sketches:
                 counts = counts + acc.kmeans_reassigned(self._km_state, s, a0)
                 rows += s.n
-            self._reassign_history.append((np.asarray(counts), rows))
+            with obs.span("readback", site="reassign_counts"):
+                self._reassign_history.append((np.asarray(counts), rows))
         self._km_step_sketches = []
 
     # --------------------------------------------------- multi-process fold --
@@ -1360,6 +1402,10 @@ class SparsifiedKMeans(SketchedEstimator):
     def _step_flush(self) -> None:
         super()._step_flush()
         self._flush_step()
+
+    def _fold_state(self):
+        return (super()._fold_state(), self._km_state, self._km_pending,
+                self._km_step_parts)
 
     # ------------------------------------------------------- scanned ingest --
 
@@ -1394,7 +1440,8 @@ class SparsifiedKMeans(SketchedEstimator):
         self._km_state = carry
         self.count_ += steps * rows_per_step
         if self.track_reassignments:
-            counts = np.asarray(ys)  # (steps, n_init)
+            with obs.span("readback", site="reassign_counts"):
+                counts = np.asarray(ys)  # (steps, n_init)
             for t in range(steps):
                 self._reassign_history.append((counts[t], rows_per_step))
 
@@ -1409,14 +1456,15 @@ class SparsifiedKMeans(SketchedEstimator):
                 raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
             centers_pre, obj = acc.kmeans_finalize(self._km_state)
             if self.track_reassignments and self._reassign_history:
-                best = int(np.argmin(np.asarray(self._km_state.obj)))
+                with obs.span("readback", site="objective"):
+                    best = int(np.argmin(np.asarray(self._km_state.obj)))
                 cnt = np.array([c[best] for c, _ in self._reassign_history])
                 rows = np.array([max(r, 1) for _, r in self._reassign_history])
                 self.reassign_counts_ = cnt
                 self.reassign_fraction_ = cnt / rows
             self.labels_ = None
             self.n_iter_ = None
-            self.count_ = int(self._km_state.count)
+            self.count_ = _read_count(self._km_state)
         else:
             s_all = self._reducer.concat()
             init_key = fold_in_str(self.spec_.key, "api-kmeans")

@@ -87,8 +87,9 @@ class SharedSketchRun:
         return self
 
     def sync(self) -> "SharedSketchRun":
-        """Block until the shared pass's last sketch is materialized (the
-        public ingest barrier — what api_bench times)."""
+        """Block until the shared pass's last sketch and every consumer's
+        fold state are materialized (the public ingest barrier — what
+        api_bench times)."""
         self.cursor.sync()
         return self
 

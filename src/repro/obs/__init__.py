@@ -1,21 +1,25 @@
 """repro.obs — full-stack telemetry: metrics, tracing, structured step logs.
 
-Dependency-free (stdlib + numpy; jax touched only lazily for profiler
-annotations). The pieces:
+Stdlib and numpy, plus ``jax.profiler`` for the span annotations where jax
+is installed. The pieces:
 
 - :class:`MetricsRegistry` — thread-safe counters / gauges / histograms
   (p50/p95/p99 from a bounded reservoir), label-keyed series, an in-process
   ``snapshot()`` API, and a shared no-op mode so disabled telemetry is free.
-- :func:`span` / :func:`timed` — nesting wall-time tracing aggregated per
-  dotted path, passed through ``jax.profiler.TraceAnnotation`` so the same
-  names appear in XLA profiles.
+- :func:`span` — nested host spans named by dotted path, each passed
+  through ``jax.profiler.TraceAnnotation`` with its attributes, so a
+  profile shows them on the device's clock; a span records its wall time
+  into a registry only where its caller passes one.
 - :class:`StepLogger` / :func:`read_jsonl` — structured JSONL step records.
 - :func:`render_exposition` / :class:`MetricsServer` — Prometheus-style text
   exposition and a stdlib scrape endpoint.
 - :func:`quantiles` — THE shared percentile helper (benchmarks and launch
   drivers compute latency percentiles through it).
 
-Wired consumers: ``StreamEngine.run(telemetry=)`` (per-step engine metrics),
+Wired consumers: the ingest path (``SketchCursor`` spans ``ingest.*`` for
+each call, chunk, sketch dispatch, consumer fold, host→device copy and
+device→host readback; ``finalize.<kind>``), ``StreamEngine.run(telemetry=)``
+(per-step engine metrics),
 ``SketchService`` (its legacy ``stats`` dict is now a registry snapshot),
 ``repro.cluster.heartbeat`` (per-host liveness gauges on the EngineState wire
 format), and the ``repro.kernels.ops`` dispatch counters
@@ -43,5 +47,4 @@ from repro.obs.tracing import (  # noqa: F401
     current_path,
     span,
     span_totals,
-    timed,
 )

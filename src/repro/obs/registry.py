@@ -250,7 +250,7 @@ class MetricsRegistry:
 
 
 #: registry handed to call sites that don't thread one through explicitly
-#: (kernel dispatch counters, bare span() calls).
+#: (kernel dispatch counters).
 _default = MetricsRegistry()
 #: always-disabled registry for explicit "no telemetry" wiring.
 NULL_REGISTRY = MetricsRegistry(enabled=False)

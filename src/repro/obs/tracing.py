@@ -1,115 +1,111 @@
-"""span()/timed() — nested wall-time tracing that aggregates per name.
+"""span() — nested host spans on the profiler's clock, optionally aggregated.
 
-A ``span("engine.update")`` times its block and folds the duration into the
-owning registry's ``span`` histogram under the span's *path* — nested spans
-dot-join (``engine.step.source``), so one histogram series exists per unique
-nesting path and :func:`span_totals` reads back an aggregated
-``{path: {count, total_s, ...}}`` view without any tree bookkeeping at
-runtime. The nesting stack is thread-local, so worker threads trace
+``with span("chunk", rows=n):`` opens a span whose *path* dot-joins it onto
+the innermost open span of this thread (``ingest.partial_fit.chunk``), so a
+reader of a trace rebuilds the tree from names alone. Every span passes
+through :class:`jax.profiler.TraceAnnotation` under its full path, with its
+keyword attributes as the event's stats: while the profiler records, the
+span lands on the host plane beside the device's operations, on the same
+clock. With no profiler running no annotation is made, since it would
+record nothing.
+
+A span records its wall time only into a registry its caller passes, as the
+``span`` histogram series of its path (read back by :func:`span_totals`);
+with none it times nothing and writes nothing. A span never waits for the
+device: it measures what the host spends in the block, which for an
+asynchronous dispatch is the enqueue.
+
+The ``call`` attribute is inherited: a span that names none carries its
+parent's, so every span of one top-level call shares the caller's
+identifier. The nesting stack is thread-local, so worker threads trace
 independently.
-
-Spans pass through :class:`jax.profiler.TraceAnnotation` (lazily imported; a
-no-op when jax is absent or the profiler is off), so the same names show up
-as trace events in XLA profiles — the host-side twin of the
-``jax.named_scope`` annotations inside the engine's jitted update.
-
-:func:`timed` wraps a callable in a span per call and additionally records
-the *first* call under ``<name>.first`` — for jitted functions that first
-call is compile+execute, so the compile cost is separated from the
-steady-state distribution instead of polluting its quantiles.
 """
 from __future__ import annotations
 
-import functools
 import threading
 import time
-from contextlib import contextmanager
 
-from repro.obs.registry import MetricsRegistry, default_registry
-
-_tls = threading.local()
+from repro.obs.registry import MetricsRegistry
 
 SPAN_METRIC = "span"
 
+try:  # the obs package imports without jax
+    from jax.profiler import TraceAnnotation as _Annotation
 
-def _stack() -> list:
-    s = getattr(_tls, "stack", None)
-    if s is None:
-        s = _tls.stack = []
-    return s
+    _profiling = _Annotation.is_enabled
+except ImportError:  # pragma: no cover - jax is installed wherever spans run
+    _Annotation = None
+
+    def _profiling() -> bool:
+        return False
 
 
-@functools.cache
-def _trace_annotation():
-    """jax.profiler.TraceAnnotation, or None — resolved once, lazily, so the
-    obs package imports without jax."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation
-    except Exception:  # noqa: BLE001 — any import failure means "no profiler"
-        return None
+class _Local(threading.local):
+    def __init__(self):
+        self.stack = []     # open spans, innermost last, as (path, call)
+
+
+_tls = _Local()
 
 
 def current_path() -> str | None:
     """The innermost active span path on this thread, if any."""
-    s = _stack()
-    return s[-1] if s else None
+    s = _tls.stack
+    return s[-1][0] if s else None
 
 
-@contextmanager
-def span(name: str, registry: MetricsRegistry | None = None,
-         annotate: bool = True):
-    """Time a block; record seconds into ``registry.histogram("span",
-    name=<dotted path>)``. Yields the path."""
-    reg = registry if registry is not None else default_registry()
-    stack = _stack()
-    path = f"{stack[-1]}.{name}" if stack else name
-    stack.append(path)
-    ann_cls = _trace_annotation() if annotate else None
-    ann = ann_cls(path) if ann_cls is not None else None
-    if ann is not None:
-        ann.__enter__()
-    t0 = time.perf_counter()
-    try:
-        yield path
-    finally:
-        dt = time.perf_counter() - t0
+def span(name: str, registry: MetricsRegistry | None = None, **attrs) -> "_Span":
+    """A span named ``name`` under the innermost open one; ``with span(...)
+    as path`` yields its dotted path. ``registry`` receives the span's
+    seconds; ``attrs`` become the trace annotation's stats."""
+    return _Span(name, registry, attrs)
+
+
+class _Span:
+    __slots__ = ("_name", "_reg", "_attrs", "_ann", "_t0")
+
+    def __init__(self, name, registry, attrs):
+        self._name = name
+        self._reg = registry
+        self._attrs = attrs
+
+    def __enter__(self) -> str:
+        stack = _tls.stack
+        attrs = self._attrs
+        if stack:
+            parent, call = stack[-1]
+            path = parent + "." + self._name
+            if call is not None and "call" not in attrs:
+                attrs["call"] = call
+        else:
+            path = self._name
+        stack.append((path, attrs.get("call")))
+        if _profiling():
+            self._ann = ann = _Annotation(path, **attrs)
+            ann.__enter__()
+        else:
+            self._ann = None
+        if self._reg is not None:
+            self._t0 = time.perf_counter()
+        return path
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        reg = self._reg
+        if reg is not None:
+            dt = time.perf_counter() - self._t0
+        ann = self._ann
         if ann is not None:
             ann.__exit__(None, None, None)
-        stack.pop()
-        reg.histogram(SPAN_METRIC, path=path).observe(dt)
+        path = _tls.stack.pop()[0]
+        if reg is not None:
+            reg.histogram(SPAN_METRIC, path=path).observe(dt)
 
 
-def timed(name: str, registry: MetricsRegistry | None = None):
-    """Decorator form of :func:`span`; splits the first call (compile, for
-    jitted fns) out under ``<name>.first``."""
-
-    def deco(fn):
-        first_done = [False]
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            reg = registry if registry is not None else default_registry()
-            t0 = time.perf_counter()
-            with span(name, reg):
-                out = fn(*args, **kwargs)
-            if not first_done[0]:
-                first_done[0] = True
-                reg.histogram(SPAN_METRIC, path=f"{name}.first").observe(
-                    time.perf_counter() - t0)
-            return out
-
-        return wrapper
-
-    return deco
-
-
-def span_totals(registry: MetricsRegistry | None = None) -> dict[str, dict]:
+def span_totals(registry: MetricsRegistry) -> dict[str, dict]:
     """Aggregated per-path span view: ``{path: {count, total_s, p50, p95,
-    p99, max}}`` — the read side of :func:`span`."""
-    reg = registry if registry is not None else default_registry()
+    p99, max}}`` — the read side of a :func:`span` given ``registry``."""
     out: dict[str, dict] = {}
-    for m in reg.metrics():
+    for m in registry.metrics():
         if m.name == SPAN_METRIC and m.kind == "histogram":
             s = m.summary()
             out[m.labels.get("path", "")] = {

@@ -65,19 +65,22 @@ class EngineTelemetry:
 
     - counters ``engine.steps`` / ``engine.rows`` / ``engine.checkpoints``
       (+ ``engine.reassigned`` when the K-means config tracks reassignments);
-    - histograms ``engine.step_seconds`` / ``engine.source_seconds`` /
-      ``engine.update_seconds`` / ``engine.checkpoint_seconds`` — wall time of
-      the whole step, the host-side batch generation, the jitted update
-      dispatch, and checkpoint writes (the update's *internal* sketch/fold/
-      psum phases are jax.named_scope-annotated, so an XLA profile breaks the
-      device step down further — see ``_build_update``);
+    - histogram ``engine.step_seconds`` — wall time of the whole step;
+    - the ``span`` histogram series of paths ``engine.source`` (host-side
+      batch generation), ``engine.update`` and ``engine.checkpoint``
+      (checkpoint writes).
+      ``engine.update`` is dispatch time: the jitted update returns once it
+      is enqueued, and nothing waits for the device. Its device time is in
+      a profile, where the update's sketch/fold/psum phases are
+      jax.named_scope-annotated (see ``_build_update``);
     - gauges ``engine.rows_per_sec`` (cumulative over this run) and
       ``engine.state_bytes`` (accumulator footprint — constant in stream
       length by construction, so a drift here is a leak).
 
     ``step_logger``/``log_every`` add a structured JSONL record per logged
-    step (step, rows, rows/sec, phase seconds, reassign fraction, state
-    bytes, checkpoint timestamps); ``on_step`` receives the same record dict
+    step (step, rows, rows/sec, phase seconds — ``update_s`` being the
+    update's dispatch time — reassign fraction, state bytes, checkpoint
+    timestamps); ``on_step`` receives the same record dict
     (the cluster launcher's heartbeat hook).
     """
 
@@ -473,8 +476,6 @@ class StreamEngine:
             reg = tel._reg()
             c_steps, c_rows = reg.counter("engine.steps"), reg.counter("engine.rows")
             h_step = reg.histogram("engine.step_seconds")
-            h_source = reg.histogram("engine.source_seconds")
-            h_update = reg.histogram("engine.update_seconds")
             g_rate = reg.gauge("engine.rows_per_sec")
             g_bytes = reg.gauge("engine.state_bytes")
             rows_run, run_t0 = 0, time.perf_counter()
@@ -502,7 +503,6 @@ class StreamEngine:
                         self.save_state(checkpoint_dir, step + 1, state, seed=seed)
                     ckpt_s = time.perf_counter() - t3
                     reg.counter("engine.checkpoints").inc()
-                    reg.histogram("engine.checkpoint_seconds").observe(ckpt_s)
             if tel is not None:
                 rows_step = int(x.shape[0]) * int(x.shape[1])
                 rows_run += rows_step
@@ -513,13 +513,12 @@ class StreamEngine:
                 c_steps.inc()
                 c_rows.inc(rows_step)
                 h_step.observe(t2 - t0)
-                h_source.observe(t1 - t0)
-                h_update.observe(t2 - t1)
                 g_rate.set(rows_run / max(elapsed, 1e-9))
                 g_bytes.set(state_bytes)
                 record = {"step": step, "rows": rows_step, "rows_total": rows_run,
                           "rows_per_sec": round(rows_run / max(elapsed, 1e-9), 1),
                           "source_s": round(t1 - t0, 6),
+                          # dispatch time: nothing waits for the device
                           "update_s": round(t2 - t1, 6),
                           "state_bytes": state_bytes}
                 if ckpt_s is not None:

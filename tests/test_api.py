@@ -216,6 +216,30 @@ def test_fit_many_continued_ingest():
     assert mean_c.count_ == 400
 
 
+@pytest.mark.parametrize("algorithm", ("minibatch", "lloyd"))
+def test_sync_waits_for_every_consumer_state(monkeypatch, algorithm):
+    """sync() is the ingest barrier: it returns only once the last sketch and
+    every registered consumer's fold state are ready on the device."""
+    x = np.asarray(jax.random.normal(KEY, (400, 32)))
+    plan = _plan(backend="stream", batch_size=100)
+    km = SparsifiedKMeans(3, plan, key=3, algorithm=algorithm)
+    cov_c = SparsifiedCov(plan, key=3)
+    run = fit_many(plan, [km, cov_c], x, finalize=False)
+    waited = []
+    real = jax.block_until_ready
+
+    def spy(tree):
+        waited.extend(jax.tree_util.tree_leaves(tree))
+        return real(tree)
+
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    run.sync()
+    states = [km._km_state, km._reducer.parts, cov_c._reducer.state]
+    leaves = jax.tree_util.tree_leaves(states)
+    assert leaves and all(leaf.is_ready() for leaf in leaves)
+    assert {id(leaf) for leaf in leaves} <= {id(w) for w in waited}
+
+
 def test_reset_detaches_from_shared_cursor():
     """reset() must unregister from a live shared pass — the old run keeps
     feeding the OTHER consumers only, never the reset estimator."""

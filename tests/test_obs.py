@@ -97,17 +97,75 @@ def test_span_nesting_and_totals():
     assert totals["outer"]["total_s"] >= totals["outer.inner"]["total_s"]
 
 
-def test_timed_splits_first_call():
+class _Recorder:
+    """Stands in for jax.profiler.TraceAnnotation: records (path, attrs)."""
+
+    def __init__(self, path, **attrs):
+        self.path, self.attrs = path, attrs
+        _Recorder.seen.append(self)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+def test_span_annotates_full_path_and_attrs(monkeypatch):
+    from repro.obs import tracing
+
+    monkeypatch.setattr(tracing, "_Annotation", _Recorder)
+    monkeypatch.setattr(tracing, "_profiling", lambda: True)
+    _Recorder.seen = []
+    with obs.span("ingest.partial_fit", call=4, rows=64) as top:
+        with obs.span("chunk", chunk=0, rows=32) as mid:
+            with obs.span("fold.kmeans", consumer=1):
+                pass
+    with obs.span("other"):
+        pass
+    assert (top, mid) == ("ingest.partial_fit", "ingest.partial_fit.chunk")
+    got = [(r.path, r.attrs) for r in _Recorder.seen]
+    assert got == [
+        ("ingest.partial_fit", {"call": 4, "rows": 64}),
+        ("ingest.partial_fit.chunk", {"chunk": 0, "rows": 32, "call": 4}),
+        ("ingest.partial_fit.chunk.fold.kmeans", {"consumer": 1, "call": 4}),
+        ("other", {})]
+    assert obs.current_path() is None
+
+
+def test_span_without_registry_writes_nothing():
     reg = obs.MetricsRegistry()
+    prev = obs.set_default_registry(reg)
+    try:
+        with obs.span("outer"):
+            with obs.span("inner", rows=3):
+                pass
+    finally:
+        obs.set_default_registry(prev)
+    assert reg.metrics() == [] and obs.span_totals(reg) == {}
 
-    @obs.timed("fn", reg)
-    def fn(x):
-        return x + 1
 
-    assert fn(1) == 2 and fn(2) == 3 and fn(3) == 4
-    totals = obs.span_totals(reg)
-    assert totals["fn"]["count"] == 3
-    assert totals["fn.first"]["count"] == 1
+def test_span_records_into_callers_registry():
+    mine, default = obs.MetricsRegistry(), obs.MetricsRegistry()
+    prev = obs.set_default_registry(default)
+    try:
+        with obs.span("engine.update", mine, step=3):
+            with obs.span("inner"):             # no registry: not recorded
+                pass
+    finally:
+        obs.set_default_registry(prev)
+    totals = obs.span_totals(mine)
+    assert list(totals) == ["engine.update"] and totals["engine.update"]["count"] == 1
+    assert totals["engine.update"]["total_s"] > 0
+    assert default.metrics() == []
+
+
+def test_span_leaves_the_stack_on_error():
+    with pytest.raises(ValueError):
+        with obs.span("outer"):
+            with obs.span("inner"):
+                raise ValueError("boom")
+    assert obs.current_path() is None
 
 
 # ---------------------------------------------------------------- JSONL -----
@@ -338,3 +396,100 @@ def test_kernel_dispatch_counters():
         assert c.value == 2
     finally:
         obs.set_default_registry(prev)
+
+
+# ------------------------------------------------ spans of the ingest path --
+
+
+def _ingest(blocks):
+    """fit_many on the first block, then partial_fit of each later one."""
+    from repro.api import Plan, SparsifiedKMeans, SparsifiedPCA, fit_many
+
+    plan = Plan(backend="stream", gamma=0.25, batch_size=32, cov_path="dense")
+    km = SparsifiedKMeans(4, plan, key=5, algorithm="minibatch")
+    pca = SparsifiedPCA(4, plan, key=5)
+    run = fit_many(plan, [km, pca], blocks[0], finalize=False)
+    for b in blocks[1:]:
+        run.partial_fit(b)
+    run.sync()
+    return km, pca
+
+
+def _host_spans(trace_dir, prefix):
+    """(name, start, end, stats) of the host events named ``prefix*``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda h: (h[1], -h[2]))
+
+
+def test_ingest_spans_in_a_cpu_profile(tmp_path):
+    """fit_many plus two partial_fit calls under the profiler: one span tree
+    per call, one chunk span per chunk, one readback per K-means step, one
+    call identifier per call — and the same state as an untraced run."""
+    rng = np.random.default_rng(3)
+    blocks = [rng.normal(size=(64, 48)).astype(np.float32) for _ in range(3)]
+    km0, pca0 = _ingest(blocks)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        km, pca = _ingest(blocks)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path, "ingest.")
+
+    top = "ingest.partial_fit"
+    calls = [h for h in spans if h[0] == top]
+    assert [h[3]["rows"] for h in calls] == [64, 64, 64]
+    ids = [h[3]["call"] for h in calls]
+    assert len(set(ids)) == 3
+    per_call: dict = {}
+    for name, s, e, st in spans:
+        (owner,) = [c for c in calls if c[1] <= s and e <= c[2]]
+        assert st["call"] == owner[3]["call"], name
+        per_call.setdefault(owner[3]["call"], []).append(name)
+    chunk = f"{top}.chunk"
+    for i, c in enumerate(ids):
+        names = per_call[c]
+        want = {top: 1, f"{top}.h2d": 1, chunk: 2, f"{chunk}.sketch": 2,
+                f"{chunk}.fold.kmeans": 2, f"{chunk}.fold.pca": 2,
+                f"{chunk}.fold.kmeans.readback": 2}
+        if i == 0:
+            want[f"{chunk}.fold.kmeans.init"] = 1
+        assert {n: names.count(n) for n in set(names)} == want
+    chunks = [h[3] for h in spans if h[0] == chunk]
+    assert [st["chunk"] for st in chunks] == list(range(6))
+    assert all(st["rows"] == 32 and st["shard"] == 0 for st in chunks)
+    assert [h[3]["bytes"] for h in spans if h[0] == f"{top}.h2d"] == [64 * 48 * 4] * 3
+    readbacks = [h for h in spans if h[0].endswith(".readback")]
+    assert len(readbacks) == len(km._reassign_history) == 6
+    assert {h[3]["site"] for h in readbacks} == {"reassign_counts"}
+    assert [h[3]["consumer"] for h in spans if h[0] == f"{chunk}.fold.pca"] == [1] * 6
+
+    for a, b in ((km0._km_state, km._km_state),
+                 (pca0._reducer.state, pca._reducer.state)):
+        for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_ingest_writes_no_span_series_without_a_registry():
+    reg = obs.MetricsRegistry()
+    prev = obs.set_default_registry(reg)
+    try:
+        rng = np.random.default_rng(4)
+        km, pca = _ingest([rng.normal(size=(64, 48)).astype(np.float32)] * 2)
+        km.finalize()
+        pca.finalize()
+    finally:
+        obs.set_default_registry(prev)
+    assert not [m for m in reg.metrics() if m.name == obs.tracing.SPAN_METRIC]
