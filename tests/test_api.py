@@ -303,9 +303,9 @@ def test_minibatch_tail_flush_and_interleaved_finalize():
     plan = _plan(backend="stream", batch_size=100, n_shards=2)
     est = SparsifiedKMeans(3, plan, key=5, algorithm="minibatch")
     est.partial_fit(x[:500])            # 5 chunks = 2 full steps + 1 pending shard
-    assert est._km_pending is not None
+    assert len(est._km_step_sketches) == 1
     est.finalize()
-    assert est._km_pending is None and est.count_ == 500
+    assert est._km_step_sketches == [] and est.count_ == 500
     c1 = np.asarray(est.centers_)
     assert np.isfinite(c1).all()
     est.partial_fit(x[500:])            # 6 more chunks, ends on a half step again
@@ -666,6 +666,117 @@ def test_fit_many_scan_matches_host_loop():
                                np.asarray(host[2].centers_), atol=1e-5)
     np.testing.assert_array_equal(np.asarray(scanned[2].reassign_counts_),
                                   np.asarray(host[2].reassign_counts_))
+
+
+def _eager_minibatch(x, plan, k, key, *, decay, track):
+    """The minibatch fold replayed one eager op at a time: per step, every
+    shard's delta against the step-start state, summed and applied once, then
+    the rows reassigned under the new centers (best hypothesis)."""
+    from repro.api.estimators import as_key
+    from repro.stream import accumulators as acc
+    from repro.utils.prng import fold_in_str
+
+    spec = plan.spec(x.shape[1], as_key(key))
+    bs, ns = plan.batch_size, plan.n_shards
+    sk = [sketch.sketch(x[i:i + bs], spec,
+                        batch_key=batch_key(spec, *plan.step_shard(j)),
+                        impl=plan.impl)
+          for j, i in enumerate(range(0, x.shape[0], bs))]
+    st = acc.kmeans_init(fold_in_str(spec.key, "api-kmeans"), sk[0], k, 3,
+                         decay=decay)
+    counts = []
+    for t in range(0, len(sk), ns):
+        step = sk[t:t + ns]
+        pairs = [acc.kmeans_delta_with_assign(st, s) for s in step]
+        delta = pairs[0][0]
+        for d, _ in pairs[1:]:
+            delta = jax.tree.map(jnp.add, delta, d)
+        st = acc.kmeans_apply(st, delta, decay=decay)
+        counts.append(sum(acc.kmeans_reassigned(st, s, a0)
+                          for s, (_, a0) in zip(step, pairs)))
+    best = int(np.argmin(np.asarray(st.obj)))
+    return st, (np.array([int(c[best]) for c in counts]) if track else None)
+
+
+@pytest.mark.parametrize("track", (True, False))
+@pytest.mark.parametrize("decay", (1.0, 0.9))
+@pytest.mark.parametrize("n_shards", (1, 2))
+def test_fit_many_scan_matches_host_loop_kmeans(n_shards, decay, track):
+    """Minibatch K-means folds one step the same way three times: the host
+    loop's compiled step, the scan body, and an eager op-by-op replay of
+    the accumulator algebra — a ragged tail (and, on 2 shards, a half step
+    that finalize flushes) included."""
+    x, _, _ = make_clusters(KEY, n=440, p=64, k=3)
+    plan = _plan(backend="stream", batch_size=100, n_shards=n_shards)
+
+    def km():
+        return SparsifiedKMeans(3, plan, key=1, algorithm="minibatch",
+                                decay=decay, track_reassignments=track)
+
+    host, scanned = km(), km()
+    fit_many(plan, [host], x)
+    fit_many(plan, [scanned], x, scan=True)
+    st, counts = _eager_minibatch(x, plan, 3, 1, decay=decay, track=track)
+
+    assert host.count_ == scanned.count_ == 440
+    for got, want in zip(jax.tree_util.tree_leaves(host._km_state),
+                         jax.tree_util.tree_leaves(st)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(scanned.centers_),
+                               np.asarray(host.centers_), atol=1e-5)
+    if track:
+        assert len(counts) == -(-5 // n_shards)     # steps of 5 chunks
+        np.testing.assert_array_equal(host.reassign_counts_, counts)
+        np.testing.assert_array_equal(scanned.reassign_counts_, counts)
+    else:
+        assert host.reassign_counts_ is None and scanned.reassign_counts_ is None
+
+
+def test_minibatch_snapshot_round_trips_device_history():
+    """state_arrays reads the reassignment history the folds left on the
+    device; load_state_arrays into a fresh estimator continues the fit as
+    if uninterrupted (counts, centers and history)."""
+    x, _, _ = make_clusters(KEY, n=800, p=32, k=3)
+    plan = _plan(backend="stream", batch_size=100, n_shards=2)
+    whole = SparsifiedKMeans(3, plan, key=5, algorithm="minibatch").fit(x)
+
+    est = SparsifiedKMeans(3, plan, key=5, algorithm="minibatch")
+    est.partial_fit(x[:400])
+    assert len(est._reassign_device) == 2 and est._reassign_history == []
+    arrs = est.state_arrays()
+    assert arrs["km.reassign_counts"].shape == (2, 3)
+    np.testing.assert_array_equal(arrs["km.reassign_rows"], [200, 200])
+
+    back = SparsifiedKMeans(3, plan, key=5, algorithm="minibatch")
+    back._cursor.ensure_spec(32)
+    back.load_state_arrays(arrs)
+    back._cursor.chunk, back._cursor.count = 4, 400
+    back.partial_fit(x[400:]).finalize()
+    assert back.count_ == 800
+    np.testing.assert_array_equal(back.reassign_counts_, whole.reassign_counts_)
+    np.testing.assert_allclose(np.asarray(back.centers_),
+                               np.asarray(whole.centers_), atol=1e-6)
+    est.partial_fit(x[400:401])          # mid-step: a shard sketch is buffered
+    with pytest.raises(RuntimeError, match="mid-step"):
+        est.state_arrays()
+
+
+def test_minibatch_history_drains_from_the_device(monkeypatch):
+    """A long ingest with no finalize reads the reassignment counts back in
+    batches of _HISTORY_ON_DEVICE steps, so the device buffers it holds stay
+    bounded; the history is the same as when read at the end."""
+    from repro.api import estimators
+
+    x, _, _ = make_clusters(KEY, n=500, p=32, k=3)
+    plan = _plan(backend="stream", batch_size=100)
+    whole = SparsifiedKMeans(3, plan, key=5, algorithm="minibatch").fit(x)
+    monkeypatch.setattr(estimators, "_HISTORY_ON_DEVICE", 2)
+    est = SparsifiedKMeans(3, plan, key=5, algorithm="minibatch")
+    est.partial_fit(x)                  # 5 steps: drained at steps 2 and 4
+    assert len(est._reassign_history) == 4 and len(est._reassign_device) == 1
+    est.finalize()
+    np.testing.assert_array_equal(est.reassign_counts_, whole.reassign_counts_)
 
 
 def test_fit_many_scan_extends_the_pass():

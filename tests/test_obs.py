@@ -401,11 +401,12 @@ def test_kernel_dispatch_counters():
 # ------------------------------------------------ spans of the ingest path --
 
 
-def _ingest(blocks):
+def _ingest(blocks, n_shards=1):
     """fit_many on the first block, then partial_fit of each later one."""
     from repro.api import Plan, SparsifiedKMeans, SparsifiedPCA, fit_many
 
-    plan = Plan(backend="stream", gamma=0.25, batch_size=32, cov_path="dense")
+    plan = Plan(backend="stream", gamma=0.25, batch_size=32, cov_path="dense",
+                n_shards=n_shards)
     km = SparsifiedKMeans(4, plan, key=5, algorithm="minibatch")
     pca = SparsifiedPCA(4, plan, key=5)
     run = fit_many(plan, [km, pca], blocks[0], finalize=False)
@@ -436,14 +437,18 @@ def _host_spans(trace_dir, prefix):
 
 def test_ingest_spans_in_a_cpu_profile(tmp_path):
     """fit_many plus two partial_fit calls under the profiler: one span tree
-    per call, one chunk span per chunk, one readback per K-means step, one
-    call identifier per call — and the same state as an untraced run."""
+    per call, one chunk span per chunk, one compiled K-means step per chunk
+    and no readback during ingest (the finalize reads the reassignment
+    history once), one call identifier per call — and the same state as an
+    untraced run."""
     rng = np.random.default_rng(3)
     blocks = [rng.normal(size=(64, 48)).astype(np.float32) for _ in range(3)]
     km0, pca0 = _ingest(blocks)
+    km0.finalize()
     jax.profiler.start_trace(str(tmp_path))
     try:
         km, pca = _ingest(blocks)
+        km.finalize()
     finally:
         jax.profiler.stop_trace()
     spans = _host_spans(tmp_path, "ingest.")
@@ -463,7 +468,7 @@ def test_ingest_spans_in_a_cpu_profile(tmp_path):
         names = per_call[c]
         want = {top: 1, f"{top}.h2d": 1, chunk: 2, f"{chunk}.sketch": 2,
                 f"{chunk}.fold.kmeans": 2, f"{chunk}.fold.pca": 2,
-                f"{chunk}.fold.kmeans.readback": 2}
+                f"{chunk}.fold.kmeans.step": 2}
         if i == 0:
             want[f"{chunk}.fold.kmeans.init"] = 1
         assert {n: names.count(n) for n in set(names)} == want
@@ -471,15 +476,52 @@ def test_ingest_spans_in_a_cpu_profile(tmp_path):
     assert [st["chunk"] for st in chunks] == list(range(6))
     assert all(st["rows"] == 32 and st["shard"] == 0 for st in chunks)
     assert [h[3]["bytes"] for h in spans if h[0] == f"{top}.h2d"] == [64 * 48 * 4] * 3
-    readbacks = [h for h in spans if h[0].endswith(".readback")]
-    assert len(readbacks) == len(km._reassign_history) == 6
-    assert {h[3]["site"] for h in readbacks} == {"reassign_counts"}
+    assert not [h for h in spans if h[0].endswith(".readback")]
+    assert [h[3]["shards"] for h in spans if h[0] == f"{chunk}.fold.kmeans.step"] == [1] * 6
+    readbacks = [h for h in _host_spans(tmp_path, "finalize.kmeans.readback")
+                 if h[3]["site"] == "reassign_counts"]
+    assert len(readbacks) == 1 and len(km.reassign_counts_) == 6
+    np.testing.assert_array_equal(km.reassign_counts_, km0.reassign_counts_)
     assert [h[3]["consumer"] for h in spans if h[0] == f"{chunk}.fold.pca"] == [1] * 6
 
     for a, b in ((km0._km_state, km._km_state),
                  (pca0._reducer.state, pca._reducer.state)):
         for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
             assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("n_shards", (1, 2))
+def test_kmeans_step_is_one_launch(tmp_path, n_shards):
+    """The host loop folds a minibatch K-means step as one compiled program:
+    each ``fold.kmeans`` span after the seeding one launches at most one
+    executable (the step, at the step's last shard; 84 eager launches
+    before), under one ``fold.kmeans.step`` span per step."""
+    rng = np.random.default_rng(5)
+    blocks = [rng.normal(size=(64, 48)).astype(np.float32) for _ in range(3)]
+    _ingest(blocks, n_shards)            # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _ingest(blocks, n_shards)
+    finally:
+        jax.profiler.stop_trace()
+    folds = _host_spans(tmp_path, "ingest.partial_fit.chunk.fold.kmeans")
+    launches = [h for h in _host_spans(tmp_path, "PjRtCpuExecutable::Execute")
+                if h[0] == "PjRtCpuExecutable::Execute"]
+
+    def inside(span):
+        return sum(1 for _, s, e, _ in launches if span[1] <= s and e <= span[2])
+
+    spans = [h for h in folds if h[0].endswith("fold.kmeans")]
+    steps = [h for h in folds if h[0].endswith("fold.kmeans.step")]
+    assert len(spans) == 6 and len(steps) == 6 // n_shards
+    assert [h[3]["shards"] for h in steps] == [n_shards] * len(steps)
+    counts = [inside(h) for h in spans[1:]]  # the first also seeds (kmeans_init)
+    assert all(c <= 2 for c in counts), counts
+    # a step's earlier shards only buffer their sketch; its last launches it
+    last = [i % n_shards == n_shards - 1 for i in range(1, 6)]
+    assert [c for c, is_last in zip(counts, last) if not is_last] == [0] * last.count(False)
+    assert all(c >= 1 for c, is_last in zip(counts, last) if is_last)
+    assert all(inside(h) == 1 for h in steps)
 
 
 def test_ingest_writes_no_span_series_without_a_registry():
