@@ -33,7 +33,7 @@ def _spiked(n, p, k):
 def _state_bytes(est: SparsifiedPCA) -> int:
     st = est._reducer.state
     if st is None:  # batch dense/compact: the retained sketch IS the state
-        return sum(s.nbytes() for s in est._reducer.parts)
+        return (est._reducer.retained.nbytes if est._reducer.retained is not None else 0)
     return sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(st))
 
 

@@ -17,7 +17,8 @@ oracles at each phase's shapes. Data is drawn on the device from ``--seed``.
    runs the chunked FWHT and a gather; the fold runs ``spmm``/``spmm_t``.
 2. p = 1024 ingest: ``fit_many`` over the mean, the dense-path covariance and
    minibatch K-means (K = 10) of ten-cluster rows, through the single-tile
-   fused sketch kernel; ``sparse_assign`` is checked on the phase's sketch.
+   fused sketch kernel; the K-means kernels' ``kmeans_assign`` and
+   ``kmeans_dists`` are checked on the phase's sketch.
 3. The sketch service at p = 4096: groups of co-registered PCA and K-means
    tenants take ingest requests and queries through ``SketchService``; every
    response must be ``ok`` and equal a direct ``fit_many`` of the same rows.
@@ -367,7 +368,7 @@ class _Context:
     def phase_ingest(self, ph: Phase) -> None:
         from repro.api import (Plan, SparsifiedCov, SparsifiedKMeans,
                                SparsifiedMean, fit_many)
-        from repro.core import ros
+        from repro.core import kmeans, ros
         from repro.kernels import ops, ref
 
         jnp = self.jax.numpy
@@ -399,13 +400,16 @@ class _Context:
         signs = ros.signs_for(spec.signs_key(), spec.p_pad)
         self.kernel_parity(ph, "sketch_fused (single tile)", s.values,
                            ref.ref_sketch_fused(x[:batch], signs, s.indices))
-        d_k, a_k = ops.sparse_assign(s.values, s.indices, km.centers_pre_, mode=self.path)
+        vt, it = kmeans.rows_transposed(s.values, s.indices)
+        n_s = s.values.shape[0]
+        d_k = ops.kmeans_dists(vt, it, km.centers_pre_, mode=self.path)[:, :n_s]
+        a_k = ops.kmeans_assign(vt, it, km.centers_pre_[None], mode=self.path)[0][0, :n_s]
         with self.jax.default_matmul_precision("highest"):
-            d_r, a_r = ref.ref_sparse_assign(s.values, s.indices, km.centers_pre_)
-        self.kernel_parity(ph, f"sparse_assign dists (K={k})", d_k, d_r)
-        ph.require("sparse_assign argmin equals the oracle's",
-                   bool(np.array_equal(np.asarray(a_k), np.asarray(a_r))))
-        self.check_dispatch(ph, before, ("sketch_fused", "sparse_assign"))
+            d_r = ref.ref_kmeans_dists(s.values.T, s.indices.T, km.centers_pre_)
+        self.kernel_parity(ph, f"kmeans_dists (K={k})", d_k, d_r)
+        ph.require("kmeans_assign argmin equals the oracle's",
+                   bool(np.array_equal(np.asarray(a_k), np.asarray(jnp.argmin(d_r, axis=0)))))
+        self.check_dispatch(ph, before, ("sketch_fused", "kmeans_assign", "kmeans_dists"))
 
         with self.jax.default_matmul_precision("highest"):
             _, want = fit(plan.replace(impl="ref"), x)

@@ -132,6 +132,68 @@ def _read_count(state) -> int:
         return int(state.count)
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _retain(values_t, indices_t, values, indices, at):
+    """Write one chunk's rows into the retained buffers at column ``at``."""
+    return (jax.lax.dynamic_update_slice(values_t, values.astype(jnp.float32).T, (0, at)),
+            jax.lax.dynamic_update_slice(indices_t, indices.astype(jnp.int32).T, (0, at)))
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+def _regrow(values_t, indices_t, cap: int):
+    pad = ((0, 0), (0, cap - values_t.shape[1]))
+    return jnp.pad(values_t, pad), jnp.pad(indices_t, pad)
+
+
+@jax.tree_util.register_pytree_node_class
+class RetainedSketch:
+    """Every retained row of the sketch, held once on the device.
+
+    ``values_t`` and ``indices_t`` are (m, capacity) buffers, row i in
+    column i (the layout of ``core.kmeans.lloyd``); each chunk is written
+    in place at the next free column, and a full buffer doubles (the
+    capacity stays a whole number of the kernels' row tiles). ``n`` rows
+    are filled; the columns past them are zero."""
+
+    def __init__(self, p: int, values_t=None, indices_t=None, n: int = 0):
+        self.p, self.values_t, self.indices_t, self.n = p, values_t, indices_t, n
+
+    def tree_flatten(self):
+        return (self.values_t, self.indices_t), (self.p, self.n)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(aux[0], *children, aux[1])
+
+    @property
+    def capacity(self) -> int:
+        return 0 if self.values_t is None else self.values_t.shape[1]
+
+    @property
+    def nbytes(self) -> int:
+        return 0 if self.values_t is None else self.values_t.nbytes + self.indices_t.nbytes
+
+    def append(self, s: SparseRows) -> None:
+        need = self.n + s.n
+        if need > self.capacity:
+            tile = km.row_tile()
+            cap = max(2 * self.capacity, -(-need // tile) * tile)
+            if self.values_t is None:
+                self.values_t = jnp.zeros((s.m, cap), jnp.float32)
+                self.indices_t = jnp.zeros((s.m, cap), jnp.int32)
+            else:
+                self.values_t, self.indices_t = _regrow(self.values_t, self.indices_t, cap)
+        self.values_t, self.indices_t = _retain(self.values_t, self.indices_t, s.values,
+                                                s.indices, jnp.int32(self.n))
+        self.n = need
+
+    def rows(self) -> SparseRows:
+        """The retained rows as one row-major (n, m) SparseRows (a copy)."""
+        if not self.n:
+            raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
+        return SparseRows(self.values_t[:, :self.n].T, self.indices_t[:, :self.n].T, self.p)
+
+
 class _MomentReducer:
     """Backend-dispatched reduction of sketched batches to (mean, cov, count).
 
@@ -158,7 +220,7 @@ class _MomentReducer:
         self.lowrank = (plan.cov_path == "lowrank" and track_cov and needs_moments)
         self.keep_sketch = keep_sketch or (plan.backend == "batch" and needs_moments
                                            and not self.lowrank)
-        self.parts: list[SparseRows] = []
+        self.retained = RetainedSketch(spec.p_pad) if self.keep_sketch else None
         self._step_parts: list[SparseRows] = []  # sharded: the in-flight step
         self._mesh = None
         self._omega = None
@@ -205,7 +267,7 @@ class _MomentReducer:
                 self.state = est.stream_update(self.state, s,
                                                cov_path=self._moment_cov_path)
         if self.keep_sketch:
-            self.parts.append(s)
+            self.retained.append(s)
 
     def flush_step(self) -> None:
         """Sharded: reduce the buffered step with one psum'd delta, then drop it.
@@ -257,9 +319,9 @@ class _MomentReducer:
                           self.spec.p_pad)
 
     def concat(self) -> SparseRows:
-        if not self.parts:
+        if self.retained is None:
             raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
-        return _concat_sparse(self.parts, self.spec.p_pad)
+        return self.retained.rows()
 
     def reduce(self):
         """(mean_pre, cov_pre | LowRankCov | None, count) via the plan's backend."""
@@ -731,7 +793,7 @@ class SketchedEstimator:
     def _fold_state(self):
         """The device arrays the folds so far write (what sync() waits on)."""
         r = self._reducer
-        return None if r is None else (r.state, r.parts, r._step_parts)
+        return None if r is None else (r.state, r.retained, r._step_parts)
 
     def _fold_sketch(self, s: SparseRows, step: int, shard: int) -> None:
         self._reducer.fold(s, step, shard)
@@ -1017,10 +1079,10 @@ class SketchedEstimator:
         out: dict = {"count": np.int64(self.count_)}
         if r.state is not None:
             out.update(state_mod.to_arrays(r.state))
-        if r.parts:            # retained sketches (batch moments / Lloyd)
-            out["parts.values"] = jnp.concatenate([s.values for s in r.parts])
-            out["parts.indices"] = jnp.concatenate([s.indices for s in r.parts])
-            out["parts.rows"] = np.array([s.n for s in r.parts], np.int64)
+        if r.retained is not None and r.retained.n:   # batch moments / Lloyd
+            s = r.retained.rows()
+            out["parts.values"], out["parts.indices"] = s.values, s.indices
+            out["parts.rows"] = np.array([s.n], np.int64)
         return out
 
     def load_state_arrays(self, arrs: dict) -> None:
@@ -1035,14 +1097,10 @@ class SketchedEstimator:
         if st is not None:
             r.state = st
         if "parts.values" in arrs:
-            values = jnp.asarray(arrs["parts.values"])
-            indices = jnp.asarray(arrs["parts.indices"])
-            r.parts = []
-            i = 0
-            for n in np.asarray(arrs["parts.rows"]).tolist():
-                r.parts.append(SparseRows(values[i:i + n], indices[i:i + n],
-                                          self.spec_.p_pad))
-                i += n
+            r.retained = RetainedSketch(self.spec_.p_pad)
+            r.retained.append(SparseRows(jnp.asarray(arrs["parts.values"]),
+                                         jnp.asarray(arrs["parts.indices"]),
+                                         self.spec_.p_pad))
 
     # Estimator-level checkpoint/restore — the fold state plus the cursor
     # counters, through the train.checkpoint atomic-rename protocol. restore()
@@ -1301,8 +1359,11 @@ class SparsifiedKMeans(SketchedEstimator):
     the solution settles (costs one extra assignment pass per batch).
 
     Fitted: ``centers_`` ((k, p), original domain), ``centers_pre_``,
-    ``objective_``, ``labels_``, ``n_iter_`` (lloyd), ``count_``,
-    ``reassign_counts_`` / ``reassign_fraction_`` ((steps,) arrays; minibatch).
+    ``objective_``, ``labels_`` (host array), ``n_iter_`` (lloyd), ``count_``,
+    ``reassign_counts_`` / ``reassign_fraction_`` ((steps,) arrays; minibatch);
+    lloyd also ``seed_rows_`` ((n_init, k): the retained rows each
+    hypothesis's K-means++ chose) and ``prev_centers_pre_`` (the best
+    hypothesis's centers entering its last iteration).
     """
 
     kind = "kmeans"
@@ -1487,24 +1548,42 @@ class SparsifiedKMeans(SketchedEstimator):
             self.n_iter_ = None
             self.count_ = _read_count(self._km_state)
         else:
-            s_all = self._reducer.concat()
-            init_key = fold_in_str(self.spec_.key, "api-kmeans")
-            if self.plan.backend == "sharded":
-                centers_pre, a, obj, it = sharded_mod.sharded_kmeans(
-                    s_all, self.k, init_key, self.plan.resolve_mesh(),
-                    n_init=self.n_init, max_iter=self.max_iter, tol=self.tol)
-            else:
-                centers_pre, a, obj, it = km.sparse_kmeans_core(
-                    s_all.values, s_all.indices, s_all.p, self.k, init_key,
-                    n_init=self.n_init, max_iter=self.max_iter, tol=self.tol)
-            self.labels_ = a
-            self.n_iter_ = int(it)
+            centers_pre, obj = self._finalize_lloyd()
         self.centers_pre_ = centers_pre
         self.centers_ = sketch_mod.unmix_dense(centers_pre, self.spec_)
         self.objective_ = obj
         self.refine_passes_ = 0           # refine() overwrites after its replay
         self.refine_reassign_counts_ = None
         self.refine_reassign_fraction_ = None
+
+    def _finalize_lloyd(self):
+        """Alg. 1 over the retained sketch (``core.kmeans.lloyd``): the
+        seeding and the iterations are dispatched without a wait, and their
+        results come back in one read (``readback`` ``site=labels``)."""
+        r = self._reducer.retained
+        if r is None or not r.n:
+            raise RuntimeError("no batches folded yet — call fit()/partial_fit() first")
+        reg = obs.default_registry()
+        reg.gauge("kmeans.retained.rows").set(r.n)
+        reg.gauge("kmeans.retained.bytes").set(r.nbytes)
+        init_key = fold_in_str(self.spec_.key, "api-kmeans")
+        kw = dict(n_init=self.n_init, max_iter=self.max_iter, tol=self.tol,
+                  impl=self.plan.impl)
+        if self.plan.backend == "sharded":
+            fit = sharded_mod.sharded_lloyd(r.rows(), self.k, init_key,
+                                            self.plan.resolve_mesh(), axes=(self.plan.axis,),
+                                            **kw)
+        else:
+            fit = km.lloyd(r.values_t, r.indices_t, r.n, r.p, self.k, init_key, **kw)
+        with obs.span("readback", site="labels"):
+            labels, n_iter, seeds, steps = jax.device_get(
+                (fit.labels, fit.n_iter, fit.seed_rows, fit.steps))
+        reg.counter("kmeans.lloyd.iterations").inc(int(steps))
+        self.labels_ = labels
+        self.n_iter_ = int(n_iter)
+        self.seed_rows_ = seeds
+        self.prev_centers_pre_ = fit.prev_centers
+        return fit.centers, fit.objective
 
     def predict(self, x) -> jax.Array:
         """Nearest-center labels for new rows (sketched with a one-shot mask)."""

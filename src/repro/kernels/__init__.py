@@ -3,7 +3,8 @@
 - fwht:          fused ROS preconditioning y = H(d⊙x) — Kronecker MXU form
 - sketch_fused:  the FULL compression operator (precondition → sample) in one
                  VMEM round trip — the streaming-ingest fast path
-- sparse_assign: sparsified K-means assignment on compact sparse rows
+- sparse_assign: Alg. 1's passes over a retained sketch (K-means++ candidate
+                 distances, assignment argmin/min, Eq. 39 sums and counts)
 - spmm:          sparse-times-dense pair (W·Omega and Wᵀ·T) feeding the
                  low-rank spectral accumulators without densifying the batch;
                  p-tiled so the VMEM footprint is bounded at any p

@@ -51,14 +51,54 @@ def hd_precondition(x: jax.Array, signs: jax.Array, mode: str = "auto") -> jax.A
     return _fwht.hd_precondition(x, signs, interpret=(mode == "interpret"))
 
 
-def sparse_assign(values: jax.Array, indices: jax.Array, centers: jax.Array, mode: str = "auto"):
-    """(dists, argmin) for sparsified K-means assignment."""
+def _kmeans_mode(mode: str, p: int, k: int, groups: int) -> str:
+    """The backend of one K-means pass over a retained sketch: the kernel
+    where it can tile the shape, else the jnp oracle (an explicit
+    ``kernel`` request that it cannot serve raises)."""
+    explicit = mode == "kernel"
     if mode == "auto":
         mode = "kernel" if _on_tpu() else "ref"
-    _count_dispatch("sparse_assign", mode)
+    if mode not in ("kernel", "interpret"):
+        return "ref"
+    if mode == "kernel" and not _sa.supported(p, k, groups):
+        if explicit:
+            raise ValueError(f"the K-means kernels cannot tile p={p}, K={k} "
+                             f"for {groups} groups; use mode='ref'")
+        return "ref"
+    return mode
+
+
+def kmeans_assign(values_t: jax.Array, indices_t: jax.Array, centers: jax.Array,
+                  mode: str = "auto"):
+    """(labels (G, n), minima (G, n)) of the retained rows (m, n) against
+    every group of centers (G, K, p) (Eq. 36)."""
+    g, k, p = centers.shape
+    mode = _kmeans_mode(mode, p, k, g)
+    _count_dispatch("kmeans_assign", mode)
     if mode == "ref":
-        return _ref.ref_sparse_assign(values, indices, centers)
-    return _sa.sparse_assign(values, indices, centers, interpret=(mode == "interpret"))
+        return _ref.ref_kmeans_assign(values_t, indices_t, centers)
+    return _sa.assign(values_t, indices_t, centers, interpret=(mode == "interpret"))
+
+
+def kmeans_update(values_t: jax.Array, indices_t: jax.Array, labels: jax.Array,
+                  k: int, p: int, mode: str = "auto"):
+    """(sums, counts) (G, K, p) of Eq. 39 under ``labels`` (G, n)."""
+    mode = _kmeans_mode(mode, p, k, labels.shape[0])
+    _count_dispatch("kmeans_update", mode)
+    if mode == "ref":
+        return _ref.ref_kmeans_update(values_t, indices_t, labels, k, p)
+    return _sa.update(values_t, indices_t, labels, k, p, interpret=(mode == "interpret"))
+
+
+def kmeans_dists(values_t: jax.Array, indices_t: jax.Array, centers: jax.Array,
+                 mode: str = "auto") -> jax.Array:
+    """(C, n) distances of the retained rows to ``centers`` (C, p)."""
+    c, p = centers.shape
+    mode = _kmeans_mode(mode, p, c, 1)
+    _count_dispatch("kmeans_dists", mode)
+    if mode == "ref":
+        return _ref.ref_kmeans_dists(values_t, indices_t, centers)
+    return _sa.dists(values_t, indices_t, centers, interpret=(mode == "interpret"))
 
 
 # The spmm kernels tile BOTH grid axes (row blocks × column blocks —
@@ -149,13 +189,3 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
         return jnp.take_along_axis(y, indices, axis=-1)
     _count_dispatch("sketch_fused", mode)
     return _ref.ref_sketch_fused(x, signs, indices)
-
-
-def kernel_assign_fn(mode: str = "auto"):
-    """Adapter matching core.kmeans assign_fn signature (returns distances only)."""
-
-    def fn(values, indices, centers):
-        d, _ = sparse_assign(values, indices, centers, mode=mode)
-        return d
-
-    return fn

@@ -19,15 +19,37 @@ def ref_hd_precondition(x: jax.Array, signs: jax.Array) -> jax.Array:
     return ros.fwht(x * signs[None, :])
 
 
-def ref_sparse_assign(values: jax.Array, indices: jax.Array, centers: jax.Array):
-    """Sparsified K-means assignment oracle — kernels.sparse_assign.
+def ref_kmeans_assign(values_t, indices_t, centers):
+    """Oracle of kernels.sparse_assign.assign: for centers (G, K, p), every
+    row's nearest center per group and its distance ‖z_i − R_iᵀμ_k‖²
+    (paper Eq. 36), by the plain gather. values_t, indices_t are (m, n)."""
 
-    values (n, m), indices (n, m) int32 (distinct per row), centers (K, p).
-    Returns (dists (n, K), argmin (n,) int32) of ‖z_i − R_iᵀμ_k‖² (paper Eq. 36).
-    """
-    g = centers.T[indices]                                   # (n, m, K)
-    d = jnp.sum((values[..., None] - g) ** 2, axis=1)
-    return d, jnp.argmin(d, axis=1).astype(jnp.int32)
+    def one(c):
+        d = jnp.sum((values_t[..., None] - c.T[indices_t]) ** 2, axis=0)   # (n, K)
+        return jnp.argmin(d, axis=1).astype(jnp.int32), jnp.min(d, axis=1)
+
+    return jax.lax.map(one, centers)
+
+
+def ref_kmeans_update(values_t, indices_t, labels, k: int, p: int):
+    """Oracle of kernels.sparse_assign.update: Eq. 39's per-cluster sums and
+    per-coordinate counts by the plain scatter-add; a negative label adds
+    nothing."""
+    m, n = values_t.shape
+
+    def one(lab):
+        rows = jnp.broadcast_to(jnp.where(lab < 0, k, lab)[None, :], (m, n))
+        sums = jnp.zeros((k + 1, p), jnp.float32).at[rows, indices_t].add(values_t)
+        counts = jnp.zeros((k + 1, p), jnp.float32).at[rows, indices_t].add(1.0)
+        return sums[:k], counts[:k]
+
+    return jax.lax.map(one, labels)
+
+
+def ref_kmeans_dists(values_t, indices_t, centers):
+    """Oracle of kernels.sparse_assign.dists: (C, n) distances to ``centers``
+    (C, p)."""
+    return jnp.sum((values_t[None] - centers[:, indices_t]) ** 2, axis=1)
 
 
 def _spmm_out_dtype(a, b) -> jnp.dtype:

@@ -1,19 +1,31 @@
-"""Sparsified K-means assignment kernel (paper Eq. 36) on compact sparse rows.
+"""Sparsified K-means passes over a retained sketch (paper Eqs. 35, 36, 39).
 
-Computes, for every sample i with kept coordinates (values V_i, indices I_i):
+The retained sketch is held transposed, one row per lane: ``values_t`` and
+``indices_t`` are (m, n), column i holding row i's kept values and their
+coordinates. For centers μ_k (K, p) and a row's densified tile W_i and 0/1
+support S_i,
 
-    d[i, k] = ‖z_i − R_iᵀ μ_k‖² = Σ_j V_ij² − 2⟨W_i, μ_k⟩ + ⟨S_i, μ_k²⟩
+    d[i, k] = ‖z_i − R_iᵀ μ_k‖² = Σ_j V_ij² − 2⟨W_i, μ_k⟩ + ⟨S_i, μ_k²⟩.
 
-where W_i is the densified sparse row and S_i its 0/1 support mask.
+Each kernel densifies a tile of rows inside VMEM, transposed to (p, rows),
+by an ``iota == index`` compare per kept slot (vector work, one select per
+element and slot, no per-coordinate walk), and contracts it on the MXU:
 
-TPU adaptation (DESIGN.md §3.2): the irregular gather μ_k[I_ij] has no fast MXU
-form, so we *densify inside VMEM* (never materializing W, S in HBM) and realize
-both inner products as dense (block_rows × p) @ (p × K) MXU matmuls. HBM traffic
-stays compact — 8·n·m bytes in, 4·n·(K+1) out — so the paper's γ saving survives
-as a *bandwidth* saving while the arithmetic runs at MXU rate. Densification is
-the SMEM-scalar walk of kernels.walk.scatter (indices are distinct per row, so
-each element is stored once); its trip count is block_rows·m, amortized across
-the two matmuls that follow. Σ_j V_ij² is read back off the densified tile.
+- :func:`assign`: the argmin over K of every row and its minimum, for G
+  groups of K centers at once (the n_init hypotheses); K is tiled, and
+  only (G, n) labels and minima reach HBM;
+- :func:`update`: the Eq. 39 sums Σ_{a_i = k} W_i and per-coordinate counts
+  Σ_{a_i = k} S_i as one-hot (K-tile × rows) products against the tile;
+  a row whose label is negative adds nothing;
+- :func:`dists`: every row's distance to a few candidate centers (the
+  K-means++ step), (C, n).
+
+Rows are tiled by ``ROW_TILE`` (the caller pads n to it: a padded column's
+distances are meaningless and its label must be masked); K by ``k_tile``.
+Full f32 precision throughout: the centers' dot with the values runs at
+``MXU_PRECISION``; where one operand is 0/1 (the support, the one-hot
+labels), which bfloat16 holds exactly, the other is split into three
+bfloat16 parts, so three exact bf16 passes give the f32 result.
 """
 from __future__ import annotations
 
@@ -21,88 +33,235 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import walk
-from repro.kernels.fwht import MXU_PRECISION, VMEM_BUDGET
+from repro.kernels.fwht import MXU_PRECISION
+
+ROW_TILE = 512            # rows per grid step (lanes of the densified tile)
+LANES = 128
+_SLAB = 64                # sublanes of the densify's register-resident slab
+# the kernels hold the (p, rows) tile and its support, and per K tile the
+# centers (assign) or the sums and counts (update) of every group,
+# double-buffered; 48 MiB of the v5e core's 128 MiB of VMEM
+VMEM_LIMIT = 48 << 20
+_NT = (((1,), (1,)), ((), ()))   # contract the row axis of both operands
 
 
-def _kernel(vals_ref, idx_ref, ctr_t_ref, ctr2_t_ref, dist_ref, amin_ref,
-            w_ref, s_ref, *, bn: int, m: int):
-    walk.scatter(w_ref, vals_ref, idx_ref, bn=bn, m=m, s_ref=s_ref)
-    w = w_ref[...]
-    v2 = jnp.sum(w * w, axis=1, keepdims=True)                # (bn, 1)
-    f32 = jnp.float32
-    cross = jax.lax.dot(w.astype(ctr_t_ref.dtype), ctr_t_ref[...],
-                        precision=MXU_PRECISION, preferred_element_type=f32)
-    mask2 = jax.lax.dot(s_ref[...].astype(ctr2_t_ref.dtype), ctr2_t_ref[...],
-                        precision=MXU_PRECISION, preferred_element_type=f32)
-    d = v2 - 2.0 * cross + mask2
-    dist_ref[...] = d.astype(dist_ref.dtype)
-    amin_ref[...] = jnp.argmin(d, axis=1).astype(jnp.int32)[:, None]
+def k_tile(k: int, groups: int, p: int) -> int:
+    """Centers per K tile: as many as fit, a multiple of 8, at most 256.
+
+    Per center of every group the assign kernel holds μ and μ² and the
+    update kernel the sums and counts, each double-buffered: 16·p bytes."""
+    fit = (VMEM_LIMIT // 2) // (16 * p * groups)
+    tk = min(256, -(-k // 8) * 8, fit // 8 * 8)
+    if tk < 8:
+        raise ValueError(f"{groups} groups of centers of width p={p} do not fit "
+                         f"a K tile of 8 in {VMEM_LIMIT} bytes of VMEM")
+    return tk
 
 
-def default_block_rows(p: int, m: int, k: int, dtype=jnp.float32) -> int:
-    """Rows per tile, from what Mosaic allocates per grid step, against
-    fwht.VMEM_BUDGET.
-
-    Fixed: the two (p, K) center operands, double-buffered, K padded to 128
-    lanes. Per row: the f32 w and s scratch tiles and their casts to the
-    operand dtype, and the double-buffered (rows, K) and (rows, 1) outputs
-    (lane-padded). Capped by the SMEM windows of values and indices.
-    """
-    pp, kp = walk.pad_lanes(p), walk.pad_lanes(k)
-    isz = jnp.dtype(dtype).itemsize
-    fixed = 2 * 2 * pp * kp * isz
-    per_row = 2 * pp * (4 + isz) + 2 * (kp + walk.LANES) * 4 + 2 * kp * 4
-    br = max(8, (VMEM_BUDGET - fixed) // per_row)
-    br = int(min(128, 1 << int(np.floor(np.log2(br)))))
-    return min(br, walk.smem_block_rows(m, n_windows=2))
+def supported(p: int, k: int, groups: int) -> bool:
+    """Whether the kernels can tile this shape (else the caller uses the
+    jnp reference)."""
+    try:
+        k_tile(k, groups, p)
+    except ValueError:
+        return False
+    return 4 * p * ROW_TILE * 4 <= VMEM_LIMIT // 2
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def sparse_assign(values: jax.Array, indices: jax.Array, centers: jax.Array,
-                  block_rows: int | None = None, interpret: bool = False):
-    """(dists (n, K) f32, argmin (n,) int32) for compact sparse rows vs centers (K, p)."""
-    n, m = values.shape
-    k, p = centers.shape
-    br = block_rows or default_block_rows(p, m, k, values.dtype)
-    n_pad = -n % br
-    if n_pad:
-        values = jnp.pad(values, ((0, n_pad), (0, 0)))
-        indices = jnp.pad(indices, ((0, n_pad), (0, 0)))
-    # the scratch tiles are lane-aligned: pad p with zero-center columns
-    # that no index reaches
-    pp = walk.pad_lanes(p)
-    ctr_t = jnp.pad(centers.astype(values.dtype).T, ((0, pp - p), (0, 0)))
-    ctr2_t = jnp.pad((centers.astype(jnp.float32) ** 2).astype(values.dtype).T,
-                     ((0, pp - p), (0, 0)))
+def _densify(vals_ref, idx_ref, w_ref, s_ref):
+    """w_ref (p, rows) ← the tile's rows densified, transposed; s_ref ← their
+    0/1 supports. A (slab, 128) block is built in registers across the m
+    kept slots (a NaN marks a coordinate no slot set), then stored once."""
+    m = vals_ref.shape[0]
+    p, rows = w_ref.shape
+    slab = min(_SLAB, p)
+    n_lane = rows // LANES
 
-    dists, amin = pl.pallas_call(
-        functools.partial(_kernel, bn=br, m=m),
-        grid=((n + n_pad) // br,),
-        in_specs=[
-            walk.smem_spec(br, m, lambda i: (i, 0)),
-            walk.smem_spec(br, m, lambda i: (i, 0)),
-            pl.BlockSpec((pp, k), lambda i: (0, 0)),
-            pl.BlockSpec((pp, k), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((br, k), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n + n_pad, k), jnp.float32),
-            jax.ShapeDtypeStruct((n + n_pad, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((br, pp), jnp.float32),
-            pltpu.VMEM((br, pp), jnp.float32),
-        ],
+    def block(b, carry):
+        c0 = pl.multiple_of((b // n_lane) * slab, slab)
+        l0 = pl.multiple_of((b % n_lane) * LANES, LANES)
+        coord = jax.lax.broadcasted_iota(jnp.int32, (slab, LANES), 0) + c0
+        idx = idx_ref[:, pl.ds(l0, LANES)]
+        val = vals_ref[:, pl.ds(l0, LANES)]
+        w = jnp.full((slab, LANES), jnp.nan, jnp.float32)
+        for j in range(m):
+            w = jnp.where(coord == idx[j:j + 1, :], val[j:j + 1, :], w)
+        hit = w == w
+        w_ref[pl.ds(c0, slab), pl.ds(l0, LANES)] = jnp.where(hit, w, 0.0)
+        s_ref[pl.ds(c0, slab), pl.ds(l0, LANES)] = hit.astype(jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, (p // slab) * n_lane, block, 0)
+
+
+def _split(x):
+    """x (f32) as three bfloat16 parts whose sum is x to f32 rounding."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _exact_dot(parts, b):
+    """Σ part @ b over bf16 parts against an operand b that bf16 holds
+    exactly (0/1): every product exact, f32 sums."""
+    return sum(jnp.dot(a, b, preferred_element_type=jnp.float32) for a in parts)
+
+
+def _sq_dists(ctr, ctr2, w, s, z2):
+    """(C, rows) distances of the tile's rows to the C centers given; ``s``
+    is the tile's support in bfloat16."""
+    cross = jax.lax.dot(ctr, w, precision=MXU_PRECISION,
+                        preferred_element_type=jnp.float32)
+    return z2 - 2.0 * cross + _exact_dot(_split(ctr2), s)
+
+
+def _row_norms(vals_ref, z2_ref):
+    v = vals_ref[...]
+    z2_ref[...] = jnp.sum(v * v, axis=0, keepdims=True)
+
+
+def _assign_kernel(vals_ref, idx_ref, ctr_ref, ctr2_ref, lab_ref, min_ref,
+                   w_ref, s_ref, z2_ref, *, k: int, tk: int):
+    kt = pl.program_id(1)
+
+    @pl.when(kt == 0)
+    def _():
+        _densify(vals_ref, idx_ref, w_ref, s_ref)
+        _row_norms(vals_ref, z2_ref)
+
+    w, s, z2 = w_ref[...], s_ref[...].astype(jnp.bfloat16), z2_ref[...]
+    rows = w.shape[1]
+    kid = jax.lax.broadcasted_iota(jnp.int32, (tk, rows), 0) + kt * tk
+    for g in range(ctr_ref.shape[0]):
+        d = _sq_dists(ctr_ref[g], ctr2_ref[g], w, s, z2)
+        d = jnp.where(kid < k, d, jnp.inf)
+        dmin = jnp.min(d, axis=0, keepdims=True)
+        arg = jnp.min(jnp.where(d == dmin, kid, k), axis=0, keepdims=True)
+        # the first K tile writes; a later one replaces only a strictly
+        # smaller minimum, so ties keep the lowest index (jnp.argmin's rule)
+        prev = jnp.where(kt == 0, jnp.inf, min_ref[g:g + 1, :])
+        better = dmin < prev
+        min_ref[g:g + 1, :] = jnp.where(better, dmin, prev)
+        lab_ref[g:g + 1, :] = jnp.where(better, arg, lab_ref[g:g + 1, :])
+
+
+def _update_kernel(vals_ref, idx_ref, lab_ref, sums_ref, cnt_ref, w_ref, s_ref,
+                   *, tk: int):
+    kt, i = pl.program_id(0), pl.program_id(1)
+    _densify(vals_ref, idx_ref, w_ref, s_ref)
+
+    @pl.when(i == 0)
+    def _():
+        sums_ref[...] = jnp.zeros_like(sums_ref)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    w, s = _split(w_ref[...]), s_ref[...].astype(jnp.bfloat16)
+    rows = s.shape[1]
+    kid = jax.lax.broadcasted_iota(jnp.int32, (tk, rows), 0) + kt * tk
+    for g in range(lab_ref.shape[0]):
+        hot = (kid == lab_ref[g:g + 1, :]).astype(jnp.bfloat16)
+        sums_ref[g] += sum(jax.lax.dot_general(hot, part, _NT,
+                                               preferred_element_type=jnp.float32)
+                           for part in w)
+        cnt_ref[g] += jax.lax.dot_general(hot, s, _NT, preferred_element_type=jnp.float32)
+
+
+def _dists_kernel(vals_ref, idx_ref, ctr_ref, ctr2_ref, out_ref, w_ref, s_ref, z2_ref):
+    _densify(vals_ref, idx_ref, w_ref, s_ref)
+    _row_norms(vals_ref, z2_ref)
+    out_ref[...] = _sq_dists(ctr_ref[...], ctr2_ref[...], w_ref[...],
+                             s_ref[...].astype(jnp.bfloat16), z2_ref[...])
+
+
+def _row_specs(m: int, index_map):
+    return [pl.BlockSpec((m, ROW_TILE), index_map), pl.BlockSpec((m, ROW_TILE), index_map)]
+
+
+def _scratch(p: int, norms: bool):
+    out = [pltpu.VMEM((p, ROW_TILE), jnp.float32), pltpu.VMEM((p, ROW_TILE), jnp.float32)]
+    return out + [pltpu.VMEM((1, ROW_TILE), jnp.float32)] if norms else out
+
+
+def _params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _pad_k(centers, tk: int):
+    k = centers.shape[1]
+    pad = -k % tk
+    return jnp.pad(centers, ((0, 0), (0, pad), (0, 0))) if pad else centers
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def assign(values_t, indices_t, centers, interpret: bool = False):
+    """(labels (G, n) int32, minima (G, n) f32): each row's nearest center of
+    every group of ``centers`` (G, K, p) and its distance. n must be a
+    multiple of ``ROW_TILE``."""
+    m, n = values_t.shape
+    g, k, p = centers.shape
+    tk = k_tile(k, g, p)
+    ctr = _pad_k(centers.astype(jnp.float32), tk)
+    ctr_spec = pl.BlockSpec((g, tk, p), lambda i, kt: (0, kt, 0))
+    out_spec = pl.BlockSpec((g, ROW_TILE), lambda i, kt: (0, i))
+    return pl.pallas_call(
+        functools.partial(_assign_kernel, k=k, tk=tk),
+        grid=(n // ROW_TILE, ctr.shape[1] // tk),
+        in_specs=_row_specs(m, lambda i, kt: (0, i)) + [ctr_spec, ctr_spec],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((g, n), jnp.int32),
+                   jax.ShapeDtypeStruct((g, n), jnp.float32)],
+        scratch_shapes=_scratch(p, norms=True),
+        compiler_params=_params(),
         interpret=interpret,
-    )(values.astype(jnp.float32), indices.astype(jnp.int32), ctr_t, ctr2_t)
-    dists = dists[:n] if n_pad else dists
-    amin = (amin[:n] if n_pad else amin)[:, 0]
-    return dists, amin
+    )(values_t, indices_t, ctr, ctr * ctr)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "p", "interpret"))
+def update(values_t, indices_t, labels, k: int, p: int, interpret: bool = False):
+    """(sums (G, K, p), counts (G, K, p)) f32 of the rows under ``labels``
+    (G, n): per group and center, the sum of its rows' densified values and
+    how many of them kept each coordinate. A negative label adds nothing."""
+    m, n = values_t.shape
+    g = labels.shape[0]
+    tk = k_tile(k, g, p)
+    kp = -(-k // tk) * tk
+    out_spec = pl.BlockSpec((g, tk, p), lambda kt, i: (0, kt, 0))
+    sums, counts = pl.pallas_call(
+        functools.partial(_update_kernel, tk=tk),
+        grid=(kp // tk, n // ROW_TILE),
+        in_specs=_row_specs(m, lambda kt, i: (0, i))
+        + [pl.BlockSpec((g, ROW_TILE), lambda kt, i: (0, i))],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((g, kp, p), jnp.float32)] * 2,
+        scratch_shapes=_scratch(p, norms=False),
+        compiler_params=_params(),
+        interpret=interpret,
+    )(values_t, indices_t, labels.astype(jnp.int32))
+    return sums[:, :k], counts[:, :k]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dists(values_t, indices_t, centers, interpret: bool = False):
+    """(C, n) f32 distances of every row to each of ``centers`` (C, p)."""
+    m, n = values_t.shape
+    c, p = centers.shape
+    ctr = centers.astype(jnp.float32)
+    cp = -c % 8
+    if cp:
+        ctr = jnp.pad(ctr, ((0, cp), (0, 0)))
+    ctr_spec = pl.BlockSpec((c + cp, p), lambda i: (0, 0))
+    out = pl.pallas_call(
+        _dists_kernel,
+        grid=(n // ROW_TILE,),
+        in_specs=_row_specs(m, lambda i: (0, i)) + [ctr_spec, ctr_spec],
+        out_specs=pl.BlockSpec((c + cp, ROW_TILE), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((c + cp, n), jnp.float32),
+        scratch_shapes=_scratch(p, norms=True),
+        compiler_params=_params(),
+        interpret=interpret,
+    )(values_t, indices_t, ctr, ctr * ctr)
+    return out[:c]

@@ -281,7 +281,7 @@ def _state_nbytes(t: _Tenant) -> int:
     trees = []
     if r is not None:
         trees.append(r.state)
-        trees.append(list(r.parts))
+        trees.append(r.retained)
     for attr in ("_km_state", "_km_centers"):
         trees.append(getattr(t.est, attr, None))
     return sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(trees)

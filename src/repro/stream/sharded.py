@@ -211,8 +211,8 @@ def sharded_kmeans_step(state: acc.KMeansState, s: SparseRows, mesh,
 # --------------------------------------------- distributed-data entry points --
 # Absorbed from the retired repro.core.distributed module (paper §I's
 # distributed setting): place rows on the mesh, sketch them in place, and run
-# the sparse Lloyd solver inside the mesh context so its many small
-# reductions lower to the same psums.
+# the sparse Lloyd solver with each device's rows in place, its update's
+# sums reduced by one psum a pass.
 
 
 def shard_rows(x: jax.Array, mesh, axes=("data",)) -> jax.Array:
@@ -232,13 +232,26 @@ def sketch_sharded(x: jax.Array, spec, mesh, axes=("data",)) -> SparseRows:
         return sketch.sketch(xs, spec)
 
 
-def sharded_kmeans(s: SparseRows, k: int, key, mesh, n_init: int = 3,
-                   max_iter: int = 50, tol: float = 1e-6):
-    """Sparsified K-means on sharded sketches (assignment stays local; the
-    center/count scatter-adds psum over the data axes)."""
+def sharded_lloyd(s: SparseRows, k: int, key, mesh, axes=None, **kw):
+    """Alg. 1's Lloyd (``core.kmeans.lloyd``, its keyword options ``kw``)
+    with the sketch's rows spread over ``axes`` (the mesh's data axes
+    unless given): each device assigns its own rows, and the update's sums
+    and counts psum over the axes. Returns the ``LloydFit``."""
     from repro.core import kmeans
+    from repro.launch.mesh import dp_axes_of
 
+    axes = tuple(axes or dp_axes_of(mesh) or mesh.axis_names)
+    # the solver's gathers leave their sharding to the compiler: Auto axes
+    mesh = jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                             axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
+    vt, it = kmeans.rows_transposed(s.values, s.indices, mesh, axes)
     with jax.set_mesh(mesh):
-        return kmeans.sparse_kmeans_core(
-            s.values, s.indices, s.p, k, key, n_init=n_init, max_iter=max_iter,
-            tol=tol)
+        return kmeans.lloyd(vt, it, s.n, s.p, k, key, mesh=mesh, axes=axes, **kw)
+
+
+def sharded_kmeans(s: SparseRows, k: int, key, mesh, n_init: int = 3,
+                   max_iter: int = 50, tol: float = 1e-6, impl: str = "auto"):
+    """:func:`sharded_lloyd`'s (centers, labels, objective, n_iter)."""
+    fit = sharded_lloyd(s, k, key, mesh, n_init=n_init, max_iter=max_iter, tol=tol,
+                        impl=impl)
+    return fit.centers, fit.labels, fit.objective, fit.n_iter

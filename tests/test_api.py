@@ -234,7 +234,7 @@ def test_sync_waits_for_every_consumer_state(monkeypatch, algorithm):
 
     monkeypatch.setattr(jax, "block_until_ready", spy)
     run.sync()
-    states = [km._km_state, km._reducer.parts, cov_c._reducer.state]
+    states = [km._km_state, km._reducer.retained, cov_c._reducer.state]
     leaves = jax.tree_util.tree_leaves(states)
     assert leaves and all(leaf.is_ready() for leaf in leaves)
     assert {id(leaf) for leaf in leaves} <= {id(w) for w in waited}
@@ -285,11 +285,11 @@ def test_sharded_moments_stream_constant_memory():
     retained past its step (the old concat()-then-reduce kept everything)."""
     x = jax.random.normal(KEY, (1000, 64))
     est = SparsifiedCov(_plan(backend="sharded"), key=7).fit(x)
-    assert est._reducer.parts == [] and est._reducer._step_parts == []
+    assert est._reducer.retained is None and est._reducer._step_parts == []
     assert int(est._reducer.state.count) == 1000
     # … while Lloyd K-means still retains the sketch it clusters (Alg. 1)
     km = SparsifiedKMeans(3, _plan(backend="sharded"), key=7).fit(x)
-    assert len(km._reducer.parts) == 5
+    assert km._reducer.retained.n == 1000
 
 
 # -------------------------------------- satellite: minibatch tail flush -----

@@ -102,10 +102,34 @@ def test_spmm_compiles(shape, op):
                                vals, idx, shape((ROWS, ell)))
 
 
-def test_sparse_assign_compiles(shape):
+@pytest.mark.parametrize("op", ["assign", "update", "dists"])
+def test_sparse_assign_compiles(shape, op):
+    """The three passes of Lloyd over a retained sketch at K = 256 and
+    p_pad = 1024 (MNIST's width), three hypotheses, K-means++'s 8
+    candidates each."""
     from repro.kernels import sparse_assign
 
-    p, k, m = 1024, 10, kept(1024)
-    assert_kernel_compiles(lambda v, i, c: sparse_assign.sparse_assign(v, i, c),
-                           shape((ROWS, m)), shape((ROWS, m), jnp.int32),
-                           shape((k, p)))
+    p, k, m, g = 1024, 256, kept(1024), 3
+    vals, idx = shape((m, ROWS)), shape((m, ROWS), jnp.int32)
+    if op == "assign":
+        assert_kernel_compiles(lambda v, i, c: sparse_assign.assign(v, i, c),
+                               vals, idx, shape((g, k, p)))
+    elif op == "update":
+        assert_kernel_compiles(lambda v, i, a: sparse_assign.update(v, i, a, k, p),
+                               vals, idx, shape((g, ROWS), jnp.int32))
+    else:
+        assert_kernel_compiles(lambda v, i, c: sparse_assign.dists(v, i, c),
+                               vals, idx, shape((g * 8, p)))
+
+
+def test_lloyd_step_compiles(shape):
+    """One whole Lloyd iteration (assign, update and the Eq. 39 apply) as
+    the program the chip runs, at K = 256 and p_pad = 1024."""
+    from repro.core import kmeans as km
+
+    p, k, m, g = 1024, 256, kept(1024), 3
+    st = km.LloydState(shape((g, k, p)), shape((g, k, p)), shape((g,)), shape((g,), jnp.int32))
+    text = km.lloyd_step.lower(shape((m, ROWS)), shape((m, ROWS), jnp.int32), st,
+                               shape(()), n=ROWS - 100, max_iter=20, impl="kernel",
+                               mesh=None, axes=None).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2

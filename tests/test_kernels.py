@@ -40,16 +40,24 @@ def test_fwht_kernel_rejects_non_pow2():
 
 @pytest.mark.parametrize("shape", [(33, 256, 16, 5), (64, 1024, 64, 10), (17, 512, 128, 3), (5, 128, 2, 2)])
 def test_sparse_assign_kernel_shapes(shape):
+    """The assign and dists kernels against the gather oracles: labels exact,
+    distances to rounding (rows padded to the row tile)."""
+    from repro.core.kmeans import rows_transposed
+
     n, p, m, k = shape
     kv, ki, kc = jax.random.split(jax.random.PRNGKey(n), 3)
     vals = jax.random.normal(kv, (n, m), jnp.float32)
     u = jax.random.uniform(ki, (n, p))
     idx = jnp.sort(jax.lax.top_k(u, m)[1].astype(jnp.int32), axis=-1)
     ctr = jax.random.normal(kc, (k, p), jnp.float32)
-    d, a = sparse_assign.sparse_assign(vals, idx, ctr, interpret=True)
-    dr, ar = ref.ref_sparse_assign(vals, idx, ctr)
-    np.testing.assert_allclose(d, dr, atol=1e-3)
-    assert bool(jnp.all(a == ar))
+    vt, it = rows_transposed(vals, idx)
+    d = sparse_assign.dists(vt, it, ctr, interpret=True)[:, :n]
+    dr = ref.ref_kmeans_dists(vals.T, idx.T, ctr)
+    np.testing.assert_allclose(d, dr, rtol=1e-5, atol=1e-3)
+    a, mins = sparse_assign.assign(vt, it, ctr[None], interpret=True)
+    ar, mr = ref.ref_kmeans_assign(vals.T, idx.T, ctr[None])
+    assert bool(jnp.all(a[:, :n] == ar))
+    np.testing.assert_allclose(mins[:, :n], mr, rtol=1e-5, atol=1e-3)
 
 
 @pytest.mark.parametrize("seed", range(10))
@@ -73,26 +81,37 @@ def test_ops_wrappers_dispatch():
         ops.hd_precondition(x, s, mode="ref"),
         atol=2e-4,
     )
+    from repro.core.kmeans import rows_transposed
+
     vals = jax.random.normal(KEY, (8, 16), jnp.float32)
     idx = jnp.sort(jax.lax.top_k(jax.random.uniform(KEY, (8, 256)), 16)[1].astype(jnp.int32), axis=-1)
-    ctr = jax.random.normal(KEY, (4, 256), jnp.float32)
-    d1, a1 = ops.sparse_assign(vals, idx, ctr, mode="interpret")
-    d2, a2 = ops.sparse_assign(vals, idx, ctr, mode="ref")
-    np.testing.assert_allclose(d1, d2, atol=1e-3)
-    assert bool(jnp.all(a1 == a2))
+    ctr = jax.random.normal(KEY, (2, 4, 256), jnp.float32)
+    vt, it = rows_transposed(vals, idx)
+    a1, d1 = ops.kmeans_assign(vt, it, ctr, mode="interpret")
+    a2, d2 = ops.kmeans_assign(vt, it, ctr, mode="ref")
+    np.testing.assert_allclose(d1[:, :8], d2[:, :8], atol=1e-3)
+    assert bool(jnp.all(a1[:, :8] == a2[:, :8]))
+    s1, c1 = ops.kmeans_update(vt, it, jnp.where(jnp.arange(vt.shape[1]) < 8, a2, -1), 4, 256,
+                               mode="interpret")
+    s2, c2 = ops.kmeans_update(vt, it, jnp.where(jnp.arange(vt.shape[1]) < 8, a2, -1), 4, 256,
+                               mode="ref")
+    np.testing.assert_allclose(s1, s2, atol=1e-5)
+    np.testing.assert_array_equal(c1, c2)
 
 
-def test_kernel_assign_fn_in_lloyd():
-    """The kernel adapter slots into the Lloyd loop and matches the ref path."""
+def test_lloyd_kernel_path_matches_ref():
+    """The Lloyd solver on the kernels (interpret mode) lands where it lands
+    on the jnp oracles: same labels, centers to rounding."""
     from repro.core import kmeans as km
 
     n, p, m, k = 60, 128, 16, 3
     kv, ki = jax.random.split(KEY)
     vals = jax.random.normal(kv, (n, m), jnp.float32)
     idx = jnp.sort(jax.lax.top_k(jax.random.uniform(ki, (n, p)), m)[1].astype(jnp.int32), axis=-1)
-    mu_ref, a_ref, o_ref, _ = km.sparse_kmeans_core(vals, idx, p, k, KEY, n_init=2, max_iter=10)
-    fn = __import__("repro.kernels.ops", fromlist=["kernel_assign_fn"]).kernel_assign_fn("ref")
-    mu_k, a_k, o_k, _ = km.sparse_kmeans_core(vals, idx, p, k, KEY, n_init=2, max_iter=10, assign_fn=fn)
+    mu_ref, a_ref, o_ref, _ = km.sparse_kmeans_core(vals, idx, p, k, KEY, n_init=2, max_iter=10,
+                                                    impl="ref")
+    mu_k, a_k, o_k, _ = km.sparse_kmeans_core(vals, idx, p, k, KEY, n_init=2, max_iter=10,
+                                              impl="interpret")
     np.testing.assert_allclose(mu_ref, mu_k, atol=1e-4)
     assert bool(jnp.all(a_ref == a_k))
 

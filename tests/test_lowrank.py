@@ -166,7 +166,7 @@ def test_lowrank_never_materializes_pp():
     leaves = jax.tree.leaves(est._reducer.state)
     assert max(leaf.size for leaf in leaves) == p * ell  # y is the largest
     assert all(leaf.shape != (p, p) for leaf in leaves)
-    assert est._reducer.parts == []                      # nothing retained
+    assert est._reducer.retained is None                 # nothing retained
     assert est._reducer.state.nbytes() < 4 * p * p       # ≪ the (p,p) f32 acc
     assert est.cov_lowrank_.nbytes() <= (ell // 2 + 1) * p * 4 + ell * 4
 
