@@ -8,10 +8,10 @@ traffic drops from (2 + γ)·n·p·4 bytes to (1 + 2γ)·n·p·4, i.e. ~2.5× fo
 γ = 0.05 on the streaming-ingest path (the paper's Tables III/IV setting).
 
 The FWHT stays on the MXU via the Kronecker form (kernels.fwht.hd_tile); the
-per-row gather walks the indices as SMEM scalars and reads each kept value
-out of its aligned VMEM tile (kernels.walk). Every power of two p from 2^8 to
-2^15 compiles for v5e; above that kernels.ops composes the chunked FWHT with
-an XLA gather.
+gather reads whole tiles: each 128-lane tile of kept slots is one lane gather
+(``tpu.dynamic_gather``) of every 128-lane tile of the row block, selected by
+the index's tile. Every power of two p from 2^8 to 2^15 compiles for v5e;
+above that kernels.ops composes the chunked FWHT with an XLA gather.
 """
 from __future__ import annotations
 
@@ -33,20 +33,31 @@ MAX_P_FUSED = MAX_P_SINGLE
 
 
 def _kernel(x_ref, d_ref, ha_ref, hb_ref, idx_ref, out_ref, y_ref, *,
-            a: int, b: int, m: int):
+            a: int, b: int):
     bn, p = x_ref.shape
     y_ref[:, :p] = hd_tile(x_ref[...], d_ref, ha_ref, hb_ref, a=a, b=b)
-    walk.gather(out_ref, y_ref, idx_ref, bn=bn, m=m)
+    lanes = walk.LANES
+    for s in range(out_ref.shape[1] // lanes):
+        idx = idx_ref[:, s * lanes:(s + 1) * lanes]
+        loc, tile = idx & (lanes - 1), idx >> 7      # lanes = 2^7
+
+        def from_tile(t, acc):
+            y = y_ref[:, pl.ds(pl.multiple_of(t * lanes, lanes), lanes)]
+            got = jnp.take_along_axis(y, loc, axis=1, mode="promise_in_bounds")
+            return jnp.where(tile == t, got, acc)
+
+        out_ref[:, s * lanes:(s + 1) * lanes] = jax.lax.fori_loop(
+            0, y_ref.shape[1] // lanes, from_tile,
+            jnp.zeros((bn, lanes), jnp.float32))
 
 
 def fused_block_rows(p: int, m: int, dtype=jnp.float32) -> int:
     """Rows per tile: the FWHT tile model, minus its (rows, p) output block,
     plus the f32 copy the gather reads and the double-buffered (rows, m)
-    f32 output block; capped by the SMEM index window."""
-    extra = (walk.pad_lanes(p) * 4 + 2 * walk.pad_lanes(m) * 4
+    int32 index and f32 output blocks."""
+    extra = (walk.pad_lanes(p) * 4 + 4 * walk.pad_lanes(m) * 4
              - 2 * p * jnp.dtype(dtype).itemsize)
-    return min(default_block_rows(p, dtype, extra_bytes_per_row=extra),
-               walk.smem_block_rows(m, n_windows=1))
+    return default_block_rows(p, dtype, extra_bytes_per_row=extra)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -67,27 +78,27 @@ def sketch_fused(x: jax.Array, signs: jax.Array, indices: jax.Array,
     a, b = factor_p(p)
     br = block_rows or fused_block_rows(p, m, x.dtype)
     n_pad = -n % br
+    mp = walk.pad_lanes(m)
     if n_pad:
         x = jnp.pad(x, ((0, n_pad), (0, 0)))
-        indices = jnp.pad(indices, ((0, n_pad), (0, 0)))
+    indices = jnp.pad(indices.astype(jnp.int32), ((0, n_pad), (0, mp - m)))
     ha = hadamard_matrix(a, x.dtype) if a > 1 else jnp.zeros((1, 1), x.dtype)
     hb = hadamard_matrix(b, x.dtype)
     d2 = signs.astype(x.dtype)[None, :]
-    mp = walk.pad_lanes(m)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, a=a, b=b, m=m),
+        functools.partial(_kernel, a=a, b=b),
         grid=((n + n_pad) // br,),
         in_specs=[
             pl.BlockSpec((br, p), lambda i: (i, 0)),
             pl.BlockSpec((1, p), lambda i: (0, 0)),
             pl.BlockSpec((max(a, 1), max(a, 1)), lambda i: (0, 0)),
             pl.BlockSpec((b, b), lambda i: (0, 0)),
-            walk.smem_spec(br, m, lambda i: (i, 0)),
+            pl.BlockSpec((br, mp), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((br, mp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + n_pad, mp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br, walk.pad_lanes(p)), jnp.float32)],
         interpret=interpret,
-    )(x, d2, ha, hb, indices.astype(jnp.int32))
+    )(x, d2, ha, hb, indices)
     return out[:n, :m].astype(x.dtype)

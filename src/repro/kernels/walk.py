@@ -1,7 +1,6 @@
 """Per-element index walks that Mosaic lowers: SMEM scalars, aligned VMEM tiles.
 
-The sparse kernels (``spmm``/``spmm_t``, ``sparse_assign``, ``sketch_fused``)
-move between compact rows (values (n, m), indices (n, m)) and dense
+``spmm``/``spmm_t`` densify compact rows (values (n, m), indices (n, m)) into
 (rows, p) VMEM tiles one kept coordinate at a time. Two constraints of the TPU
 compiler shape how:
 
@@ -11,8 +10,10 @@ compiler shape how:
 - VMEM is read and written at dynamic offsets only in whole (8, 128) tiles
   (a (1, 1) slice at a dynamic row and lane is refused as an unaligned load).
   So element (i, col) is reached through its aligned tile and a one-hot mask
-  (``element_tile``): a scatter is a masked read-modify-write of one tile, a
-  gather a masked reduction of one.
+  (``element_tile``): a scatter is a masked read-modify-write of one tile.
+
+The other direction, reading kept values out of a dense tile, needs no walk:
+``sketch_fused`` gathers whole 128-lane tiles at once (``tpu.dynamic_gather``).
 
 Every kernel that walks this way keeps its dense tiles in float32, whose
 (8, 128) tiling this assumes.
@@ -110,16 +111,3 @@ def scatter(w_ref, vals_ref, idx_ref, *, bn: int, m: int, col0=0, s_ref=None):
 
     _for_each_entry(bn, m, entry)
 
-
-def gather(out_ref, y_ref, idx_ref, *, bn: int, m: int):
-    """out[i, j] = y[i, idx[i, j]] for the block's rows: (bn, ≥ m) f32
-    ``out_ref`` from the (bn, p) f32 tile ``y_ref``."""
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def entry(i, j):
-        src, hit = element_tile(i, idx_ref[i, j])
-        val = jnp.sum(jnp.where(hit, y_ref[src], 0.0), keepdims=True)
-        dst, put = element_tile(i, j)
-        out_ref[dst] = jnp.where(put, val, out_ref[dst])
-
-    _for_each_entry(bn, m, entry)

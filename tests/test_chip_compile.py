@@ -79,13 +79,16 @@ def test_fwht_chunked_compiles(shape):
                            shape((ROWS, p)), shape((p,)))
 
 
-@pytest.mark.parametrize("p", [1024, 1 << 15])
-def test_sketch_fused_compiles(shape, p):
+@pytest.mark.parametrize("p,gamma", [(1024, 0.05), (1 << 15, 0.05), (4096, 0.5)],
+                         ids=["1024", "32768", "4096-gamma0.5"])
+def test_sketch_fused_compiles(shape, p, gamma):
+    """The gather's widest case in practice is p = 4096 at γ = 0.5: 16 output
+    lane tiles, each gathered from 32 tiles of the preconditioned block."""
     from repro.kernels import sketch_fused
 
     assert_kernel_compiles(lambda x, d, i: sketch_fused.sketch_fused(x, d, i),
                            shape((ROWS, p)), shape((p,)),
-                           shape((ROWS, kept(p)), jnp.int32))
+                           shape((ROWS, kept(p, gamma)), jnp.int32))
 
 
 @pytest.mark.parametrize("op", ["spmm", "spmm_t"])

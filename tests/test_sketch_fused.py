@@ -34,6 +34,8 @@ def _case(seed, n, p, m):
     (33, 256, 16),    # a == 1 boundary
     (9, 512, 32),     # a > 1 (two-factor Kronecker)
     (21, 4096, 64),   # a > 1, wide
+    (19, 2048, 300),  # three output lane tiles
+    (45, 1024, 51),   # m not a multiple of 8; rows not a multiple of the block
 ])
 def test_fused_matches_composed_oracle(n, p, m):
     x, s, idx = _case(n * p, n, p, m)
@@ -51,6 +53,36 @@ def test_fused_ragged_row_counts(n):
     x, s, idx = _case(1000 + n, n, 512, 24)
     y = sketch_fused.sketch_fused(x, s, idx, interpret=True)
     assert y.shape == (n, 24)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ref.ref_sketch_fused(x, s, idx)),
+                               atol=3e-4)
+
+
+def _edge_indices(n, p, m, case):
+    """(n, m) sorted distinct indices that stress the lane-tile gather."""
+    if case == "lane_tile_bounds":
+        edges = [0, 127, 128, 255, p - 1]
+        rest = [c for c in range(1, p - 1, p // m) if c not in edges]
+        row = sorted(edges + rest[:m - len(edges)])
+    else:   # every kept slot of the row in one 128-lane tile
+        row = list(range(3 * 128, 3 * 128 + m))
+    return jnp.tile(jnp.asarray(row, jnp.int32), (n, 1))
+
+
+@pytest.mark.parametrize("case,n,p,m", [
+    ("lane_tile_bounds", 11, 512, 24),
+    ("lane_tile_bounds", 37, 1024, 51),
+    ("one_lane_tile", 11, 512, 24),
+    ("one_lane_tile", 37, 1024, 51),
+])
+def test_fused_gather_edge_indices(case, n, p, m):
+    """Indices on lane-tile boundaries (0, 127, 128, 255, p − 1) and rows
+    whose kept slots all lie in one 128-lane tile."""
+    x, s, _ = _case(7 * n + p, n, p, m)
+    idx = _edge_indices(n, p, m, case)
+    assert idx.shape == (n, m)
+    assert (jnp.diff(idx, axis=1) > 0).all()
+    y = sketch_fused.sketch_fused(x, s, idx, interpret=True)
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(ref.ref_sketch_fused(x, s, idx)),
                                atol=3e-4)
