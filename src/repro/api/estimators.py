@@ -49,6 +49,7 @@ from repro import refine as refine_mod
 from repro.stream import accumulators as acc
 from repro.stream import sharded as sharded_mod
 from repro.stream import state as state_mod
+from repro.stream import step as step_mod
 from repro.train import checkpoint as checkpoint_mod
 from repro.utils.prng import fold_in_str
 
@@ -205,7 +206,7 @@ class _MomentReducer:
     sketch) holds sketches past their step. "stream" folds each sketch into
     the constant-memory accumulator immediately; "sharded" buffers ONE step's
     shard sketches, reduces them with a single psum of the fixed-size delta
-    (the StreamEngine's per-step discipline), and drops them — streaming
+    (the step rule of repro.stream.step), and drops them — streaming
     per-step reduction, not concat()-then-reduce, so host memory stays
     constant in the stream length.
     """
@@ -355,104 +356,43 @@ def _concat_sparse(parts: list[SparseRows], p: int) -> SparseRows:
 # aligned full-step prefix of each partial_fit array is staged as
 # (steps, n_shards, batch_size, p) and driven through ONE jitted scan whose
 # body regenerates chunk (step, shard)'s mask key exactly as fold_rows does
-# and applies the consumers' per-step fold semantics. This mirrors
-# StreamEngine.run_scanned: same sketches, same fold order, so results match
-# the host loop to float-summation reordering — but it is NOT bit-identical
-# across backends the way the host loop is, which is why it stays opt-in.
+# and folds each step through repro.stream.step. Same sketches as the host
+# loop, so results match it to float-summation reordering — but not
+# bit-identically, which is why the scan stays opt-in.
 #
-# Consumers describe their in-scan fold with a small hashable descriptor
-# (_scan_desc) so the compiled scan is shared across estimator instances via
+# Consumers name their in-scan fold with a repro.stream.step descriptor
+# (_scan_desc), so the compiled scan is shared across estimator instances via
 # the lru_cache below; consumers whose fold cannot run inside a scan
 # (retained sketches, shard_map reductions) return None and scan=True raises.
 
 
-def _tree_sum(deltas):
-    out = deltas[0]
-    for d in deltas[1:]:
-        out = jax.tree.map(jnp.add, out, d)
-    return out
-
-
-def _scan_step_fold(desc, plan: Plan | None):
-    """desc → fold(carry, aux, step_sketches) -> (carry, y) for one scan step.
-
-    Each fold replicates the corresponding host-loop semantics exactly:
-    moment/range/fd fold the step's shard sketches in (step, shard) linear
-    order; minibatch K-means takes every shard's delta against the step-start
-    state, sums them, and applies once (the StreamEngine per-step discipline)
-    — the host loop runs that same fold, jitted, through _kmeans_fold_fn.
-    Only the range fold reads ``plan``.
-    """
-    kind = desc[0]
-    if kind == "moment":
-        cov_path = desc[1]
-
-        def fold(carry, aux, sketches):
-            for s in sketches:
-                carry = est.stream_update(carry, s, cov_path=cov_path)
-            return carry, jnp.zeros((), jnp.int32)
-    elif kind == "range":
-        def fold(carry, aux, sketches):
-            for s in sketches:
-                carry = lowrank_mod.range_update(carry, s, aux, impl=plan.impl)
-            return carry, jnp.zeros((), jnp.int32)
-    elif kind == "fd":
-        def fold(carry, aux, sketches):
-            for s in sketches:
-                carry = lowrank_mod.fd_update(carry, s)
-            return carry, jnp.zeros((), jnp.int32)
-    elif kind == "kmeans":
-        track, decay = desc[1], desc[2]
-
-        def fold(carry, aux, sketches):
-            if track:
-                pairs = [acc.kmeans_delta_with_assign(carry, s) for s in sketches]
-                new = acc.kmeans_apply(carry, _tree_sum([d for d, _ in pairs]),
-                                       decay=decay)
-                counts = _tree_sum([acc.kmeans_reassigned(new, s, a0)
-                                    for s, (_, a0) in zip(sketches, pairs)])
-                return new, counts
-            new = acc.kmeans_apply(
-                carry, _tree_sum([acc.kmeans_delta(carry, s) for s in sketches]),
-                decay=decay)
-            return new, jnp.zeros((), jnp.int32)
-    else:  # pragma: no cover - descriptors come from _scan_desc
-        raise ValueError(f"unknown scan descriptor {desc!r}")
-    return fold
-
-
-def _kmeans_fold_fn(track: bool, decay: float):
+@functools.lru_cache(maxsize=None)
+def _kmeans_fold_jit(track: bool, decay: float, *algebra):
     """The host loop's minibatch K-means step as ONE compiled program:
     (state, the step's shard sketches) -> (state, (r,) reassignment counts),
     the scan body's K-means fold. Cached on its static description so every
     estimator shares it (a benchmark's warm-up and measured runs, each
     SketchService group); jit compiles once per observed chunk shape and
-    number of shard sketches. The cache key also holds the accumulator
-    functions the fold traces: jit keeps what it traced, so where one of
-    them is replaced at run time (an instrumented or fault-planted build)
-    the step is traced again from the module as it now stands."""
-    return _kmeans_fold_jit(track, decay, acc.kmeans_delta_with_assign,
-                            acc.kmeans_apply, acc.kmeans_reassigned)
-
-
-@functools.lru_cache(maxsize=None)
-def _kmeans_fold_jit(track: bool, decay: float, *algebra):
-    fold = _scan_step_fold(("kmeans", track, decay), None)
+    number of shard sketches. The cache key also holds ``algebra``
+    (``repro.stream.step.algebra()``), the accumulator functions the fold
+    traces: jit keeps what it traced, so where one of them is replaced at run
+    time (an instrumented or fault-planted build) the step is traced again
+    from the module as it now stands."""
+    desc = ("kmeans", track, decay)
 
     def kmeans_step(state, sketches):   # names the program in device traces
-        return fold(state, None, sketches)
+        return step_mod.fold_step(desc, state, None, sketches)
 
     return jax.jit(kmeans_step)
 
 
 @functools.lru_cache(maxsize=None)
 def _build_scan_fn(plan: Plan, p: int, m: int, transform: str, impl: str,
-                   descs: tuple):
+                   descs: tuple, *algebra):
     """The jitted scan over full (step × n_shards) blocks, cached on the
     static description so repeated fit_many calls (and benchmark loops) reuse
-    one compilation per shape."""
+    one compilation per shape; ``algebra`` is keyed as in _kmeans_fold_jit."""
     n_shards = plan.n_shards
-    folds = tuple(_scan_step_fold(d, plan) for d in descs)
 
     @jax.jit
     def scan_all(carries, auxes, xs, step0, signs_key, mask_key):
@@ -467,8 +407,8 @@ def _build_scan_fn(plan: Plan, p: int, m: int, transform: str, impl: str,
                 for sh in range(n_shards)
             ]
             new, ys = [], []
-            for c, aux, fold in zip(carry, auxes, folds):
-                nc, y = fold(c, aux, sketches)
+            for c, aux, desc in zip(carry, auxes, descs):
+                nc, y = step_mod.fold_step(desc, c, aux, sketches)
                 new.append(nc)
                 ys.append(y)
             return tuple(new), tuple(ys)
@@ -612,7 +552,8 @@ class SketchCursor:
             for c in self.consumers:
                 c._scan_prepare(self, xs, step0)
             scan_fn = _build_scan_fn(plan, spec.p, spec.m, spec.transform,
-                                     ros.resolve_impl(plan.impl), descs)
+                                     ros.resolve_impl(plan.impl), descs,
+                                     *step_mod.algebra())
             carries = tuple(c._scan_carry() for c in self.consumers)
             auxes = tuple(c._scan_aux() for c in self.consumers)
             new_carries, ys = scan_fn(carries, auxes, xs, jnp.int32(step0),
@@ -836,8 +777,8 @@ class SketchedEstimator:
 
     # ------------------------------------------------------- scanned ingest --
     # Hooks for the cursor's opt-in lax.scan hot loop (cursor.scan = True /
-    # fit_many(scan=True)). _scan_desc names the in-scan fold (a hashable
-    # key into _scan_step_fold) or returns None when this consumer's fold
+    # fit_many(scan=True)). _scan_desc names the in-scan fold (a
+    # repro.stream.step descriptor) or returns None when this consumer's fold
     # cannot run inside a scan; carry/aux/absorb move the fold state across
     # the jit boundary.
 
@@ -849,7 +790,7 @@ class SketchedEstimator:
             if plan.lowrank_method == "fd":
                 return ("fd",)
             # range on sharded reduces through shard_map psums — host only
-            return None if plan.backend == "sharded" else ("range",)
+            return None if plan.backend == "sharded" else ("range", plan.impl)
         if not self._needs_moments:
             return None
         if plan.backend != "stream":
@@ -1344,8 +1285,8 @@ class SparsifiedKMeans(SketchedEstimator):
     algorithm="minibatch": the constant-memory streaming accumulators of
     ``repro.stream.accumulators`` (online Eq. 39 update, r = n_init parallel
     hypotheses) — nothing is retained but the (r, K, p_pad) centers/counts.
-    The fold is identical on every backend (per-step deltas against the
-    step-start state, as the StreamEngine computes them), so backends stay
+    The fold is the same on every backend (``repro.stream.step``: per-step
+    deltas against the step-start state), so backends stay
     tolerance-identical; ``labels_`` is None (use :meth:`predict`).
 
     Mini-batch extras (ROADMAP streaming-K-means items): ``decay`` < 1 is a
@@ -1421,10 +1362,9 @@ class SparsifiedKMeans(SketchedEstimator):
             self._flush_step()
 
     def _flush_step(self) -> None:
-        """Fold the buffered step: every shard's delta against the step-start
-        state, summed and applied once (the StreamEngine discipline, so
-        backends agree) — one jitted program on the host backends, the
-        mesh-resident shard_map step (one psum of the delta) on ``sharded``."""
+        """Fold the buffered step through ``repro.stream.step``: one jitted
+        program on the host backends, the mesh-resident shard_map step (one
+        psum of the delta) on ``sharded``."""
         if not self._km_step_sketches:
             return
         parts, self._km_step_sketches = self._km_step_sketches, []
@@ -1452,8 +1392,9 @@ class SparsifiedKMeans(SketchedEstimator):
             rows = s_cat.n      # the global step's rows, on every process
         else:
             with obs.span("step", shards=len(parts)):
-                self._km_state, counts = _kmeans_fold_fn(
-                    self.track_reassignments, self.decay)(self._km_state, parts)
+                self._km_state, counts = _kmeans_fold_jit(
+                    self.track_reassignments, self.decay,
+                    *step_mod.algebra())(self._km_state, parts)
             rows = sum(s.n for s in parts)
         if self.track_reassignments:
             self._reassign_device.append((counts, rows))

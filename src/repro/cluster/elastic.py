@@ -11,11 +11,12 @@ have).
 
 Per step, every worker's :func:`partial_step_delta` is taken against the SAME
 replicated step-start state; the fixed-size deltas are :func:`merge_deltas`'d
-(element-wise add — exactly the engine's within-step sum) and applied once by
-:func:`apply_step`. Because the per-shard deltas are identical to the original
-layout's and the apply happens once per step either way, a 4-worker run, its
-2-worker continuation, and the single-host run agree to float-summation
-reordering (tests/test_cluster.py asserts the 4→2 remap parity).
+(element-wise add) and applied once by :func:`apply_step` — the step rule of
+:mod:`repro.stream.step`, split at its reduce. Because the per-shard deltas
+are identical to the original layout's and the apply happens once per step
+either way, a 4-worker run, its 2-worker continuation, and the single-host
+run agree to float-summation reordering (tests/test_cluster.py asserts the
+4→2 remap parity).
 
 :func:`continue_elastic` is the single-host driver of that protocol (the test
 and bench harness; on a real cluster each worker runs its own
@@ -23,11 +24,13 @@ and bench harness; on a real cluster each worker runs its own
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 
+from repro.stream import step as step_mod
 from repro.stream.engine import EngineState, StreamEngine
 
 
@@ -46,18 +49,17 @@ def worker_shards(n_shards: int, n_workers: int, worker: int) -> list[int]:
 
 
 def partial_step_delta(engine: StreamEngine, state: EngineState, step: int,
-                       shards: list[int], seed: int | None = None):
-    """One worker's summed delta for ``step``: fold ONLY ``shards``' batches
-    — regenerated from the (seed, step, shard) contract and sketched under
-    their grid-fixed mask keys — against the step-start ``state``."""
+                       shards: list[int], seed: int | None = None) -> dict:
+    """One worker's summed delta for ``step``, per accumulating state field:
+    fold ONLY ``shards``' batches — regenerated from the (seed, step, shard)
+    contract and sketched under their grid-fixed mask keys — against the
+    step-start ``state``."""
     if not shards:
         raise ValueError("partial_step_delta needs at least one shard")
-    deltas = None
-    for sh in shards:
-        x = jnp.asarray(engine.source(seed, step, sh))
-        d = engine._deltas(state, engine._sketch_local(x, jnp.int32(step), sh))
-        deltas = d if deltas is None else jax.tree.map(jnp.add, deltas, d)
-    return deltas
+    sketches = [engine.sketch_shard(engine.source(seed, step, sh), jnp.int32(step), sh)
+                for sh in shards]
+    return {field: step_mod.step_delta(desc, getattr(state, field), aux, sketches)[0]
+            for field, desc, aux in engine.folds()}
 
 
 def merge_deltas(a, b):
@@ -66,10 +68,12 @@ def merge_deltas(a, b):
     return jax.tree.map(jnp.add, a, b)
 
 
-def apply_step(engine: StreamEngine, state: EngineState, delta) -> EngineState:
-    """Apply one step's merged delta ONCE — the engine's per-step discipline
-    (K-means decay and the Eq.-39 mean update happen here, exactly once)."""
-    return engine._apply(state, delta)
+def apply_step(engine: StreamEngine, state: EngineState, delta: dict) -> EngineState:
+    """Apply one step's merged delta ONCE (K-means decay and the Eq.-39 mean
+    update happen here, exactly once)."""
+    return dataclasses.replace(state, **{
+        field: step_mod.step_apply(desc, getattr(state, field), delta[field])[0]
+        for field, desc, _ in engine.folds()})
 
 
 def continue_elastic(engine: StreamEngine, steps: int, *, state: EngineState,

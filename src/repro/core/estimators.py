@@ -77,9 +77,9 @@ def cov_estimator(s: SparseRows, path: Literal["dense", "compact"] = "dense") ->
 
 
 # ----------------------------------------------------------- streaming ------
-# Minimal fold-a-batch accumulator, kept for small scripts and examples. The
-# full streaming subsystem — donated accumulators, shard_map distribution,
-# per-(step, shard) mask keys, streaming K-means — is repro.stream.StreamEngine.
+# The moment accumulator's delta/apply algebra. Every streaming driver folds
+# it a step at a time through repro.stream.step (one delta per shard sketch,
+# summed or psum'd, applied once); stream_update is that fold for one sketch.
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)

@@ -4,6 +4,7 @@
                 jitted, optionally shard_map'd loop.
 - accumulators: constant-memory delta/apply algebra (Thm-4 mean, Thm-6 cov,
                 mini-batch streaming sparsified K-means).
+- step:         the per-step fold over that algebra that every driver calls.
 - sharded:      one-shot shard_map reductions + the distributed-data entry
                 points (shard_rows / sketch_sharded / sharded_kmeans).
 - state:        the EngineState lifecycle protocol — merge algebra and

@@ -8,10 +8,10 @@ and split into
     delta(batch)  →  local, embarrassingly parallel (no collectives), then
     apply(state, delta)  →  the only state mutation,
 
-so the distributed engine can psum the *delta* (the fixed-size cross-shard
-traffic) and apply it to replicated state, while the single-device engine
-applies the same delta directly. Streaming-equals-batch (tests/test_stream.py)
-holds because finalize uses exactly the Thm-4 / Thm-6 formulas of
+so a step's deltas can be summed (psum'd under a mesh: the fixed-size
+cross-shard traffic) and applied once; :mod:`repro.stream.step` is where that
+step rule is written. Streaming-equals-batch (tests/test_stream.py) holds
+because finalize uses exactly the Thm-4 / Thm-6 formulas of
 repro.core.estimators.
 
 Three accumulators:
@@ -103,7 +103,7 @@ def kmeans_init(key: jax.Array, first_batch: SparseRows, k: int, n_init: int = 3
     )
 
 
-def kmeans_delta_with_assign(state: KMeansState, batch: SparseRows):
+def kmeans_delta_with_assign(state: KMeansState, batch: SparseRows, mask=None):
     """(delta, assign) for one batch under every hypothesis.
 
     ``assign`` (r, n) int32 are the nearest-center labels under the
@@ -111,6 +111,10 @@ def kmeans_delta_with_assign(state: KMeansState, batch: SparseRows):
     callers that also track reassignment counts (so the convergence signal
     costs ONE extra assignment pass after the apply, not a recomputation of
     this one).
+
+    ``mask`` (n,) marks the real rows of a zero-padded batch. Pad rows are
+    real points at the origin to the scatter adds (unlike the linear moment
+    deltas), so the mask zeroes their values, counts and objective terms.
     """
     values, indices = batch.values, batch.indices
     k, p = state.centers.shape[1:]
@@ -119,14 +123,21 @@ def kmeans_delta_with_assign(state: KMeansState, batch: SparseRows):
         d = sparse_sq_dists(values, indices, centers)          # (n, K)
         a = jnp.argmin(d, axis=1)
         rows = jnp.broadcast_to(a[:, None], indices.shape)
-        sums = jnp.zeros((k, p), jnp.float32).at[rows, indices].add(
-            values.astype(jnp.float32))
-        cnts = jnp.zeros((k, p), jnp.int32).at[rows, indices].add(1)
-        return sums, cnts, jnp.sum(jnp.min(d, axis=1)).astype(jnp.float32), \
-            a.astype(jnp.int32)
+        w, hits = values.astype(jnp.float32), 1
+        if mask is not None:
+            w = w * mask.astype(jnp.float32)[:, None]
+            hits = jnp.broadcast_to(mask.astype(jnp.int32)[:, None], indices.shape)
+        sums = jnp.zeros((k, p), jnp.float32).at[rows, indices].add(w)
+        cnts = jnp.zeros((k, p), jnp.int32).at[rows, indices].add(hits)
+        dmin = jnp.min(d, axis=1)
+        if mask is not None:
+            dmin = dmin * mask.astype(jnp.float32)
+        return sums, cnts, jnp.sum(dmin).astype(jnp.float32), a.astype(jnp.int32)
 
     sums, cnts, obj, assign = jax.vmap(one)(state.centers)
-    return (sums, cnts, obj, jnp.int32(values.shape[0])), assign
+    n = (jnp.int32(values.shape[0]) if mask is None
+         else jnp.sum(mask).astype(jnp.int32))
+    return (sums, cnts, obj, n), assign
 
 
 def kmeans_delta(state: KMeansState, batch: SparseRows):
@@ -170,7 +181,7 @@ def kmeans_apply(state: KMeansState, delta, decay: float = 1.0) -> KMeansState:
 
 
 def kmeans_reassigned(state: KMeansState, batch: SparseRows,
-                      prev_assign: jax.Array) -> jax.Array:
+                      prev_assign: jax.Array, mask=None) -> jax.Array:
     """(r,) int32 — how many of the batch's rows change nearest center across
     one apply: labels under ``state.centers`` (post-update) vs ``prev_assign``
     (the labels :func:`kmeans_delta_with_assign` computed pre-update).
@@ -178,12 +189,15 @@ def kmeans_reassigned(state: KMeansState, batch: SparseRows,
     The mini-batch convergence signal (ROADMAP streaming-K-means item): as the
     per-coordinate means settle, the count decays toward zero; a persistently
     high count means the stream is still reshaping the solution (or drifting,
-    under a decay factor).
+    under a decay factor). ``mask`` (n,) leaves pad rows uncounted.
     """
 
     def one(c_new, a_prev):
         a1 = jnp.argmin(sparse_sq_dists(batch.values, batch.indices, c_new), axis=1)
-        return jnp.sum(a1.astype(jnp.int32) != a_prev).astype(jnp.int32)
+        moved = a1.astype(jnp.int32) != a_prev
+        if mask is not None:
+            moved = moved * mask.astype(jnp.int32)
+        return jnp.sum(moved).astype(jnp.int32)
 
     return jax.vmap(one)(state.centers, prev_assign)
 

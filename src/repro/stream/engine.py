@@ -9,9 +9,10 @@ distributed settings, §IV–V estimators, §VI K-means):
 - **sketch** applies HD then R_i per sample with an *independent mask per
   (step, shard) batch* (fold of the spec's mask key), preserving the per-sample
   independence the estimators' guarantees hinge on;
-- **accumulate** folds each sketched batch into donated constant-memory
-  accumulators (repro.stream.accumulators) — Thm-4 mean, Thm-6 covariance, and
-  mini-batch streaming sparsified K-means;
+- **accumulate** folds each step's sketched batches into donated
+  constant-memory accumulators (repro.stream.accumulators) — Thm-4 mean,
+  Thm-6 covariance, and mini-batch streaming sparsified K-means — through the
+  per-step fold of repro.stream.step;
 - **finalize** applies the closed-form debiasing once, after the last batch.
 
 Distribution: with ``mesh=``, the update runs under ``shard_map`` — every shard
@@ -23,10 +24,8 @@ per-(step, shard) sketches, so they agree to float-sum reordering
 
 The estimator API surfaces this fused pass: ``repro.api.fit_many`` drives any
 set of consumers from one shared ``source → sketch`` cursor under the same
-(seed, step, shard) contract (:func:`normalize_source` is the shared adapter),
-with the engine's per-step discipline — summed shard deltas applied once per
-step, sharded moments reduced by one psum of the fixed-size delta and nothing
-retained past its step.
+(seed, step, shard) contract (:func:`normalize_source` is the shared adapter)
+and the same step fold.
 """
 from __future__ import annotations
 
@@ -49,6 +48,7 @@ from repro import lowrank as lowrank_mod
 from repro import obs
 from repro import refine as refine_mod
 from repro.stream import accumulators as acc
+from repro.stream import step as step_mod
 from repro.utils.prng import fold_in_str
 
 Source = Callable[[int, int, int], Any]  # (seed, step, shard) -> (b, p) array
@@ -272,119 +272,64 @@ class StreamEngine:
 
     # ------------------------------------------------------------ plumbing --
 
-    def _sketch_local(self, x, step, shard) -> SparseRows:
+    def sketch_shard(self, x, step, shard) -> SparseRows:
+        """Shard ``shard``'s batch of ``step`` sketched under its grid-fixed
+        mask key."""
         return sketch_mod.sketch(jnp.asarray(x), self.spec,
                                  batch_key=batch_key(self.spec, step, shard),
                                  impl=self.impl)
 
-    def _deltas(self, state: EngineState, batch: SparseRows):
-        md = (None if self.lowrank
-              else acc.moment_delta(batch, track_cov=self.track_cov,
-                                    cov_path=self.cov_path))
-        kd = acc.kmeans_delta(state.kmeans, batch) if state.kmeans is not None else None
-        ld = (lowrank_mod.range_delta(batch, self._omega, impl=self.impl)
-              if self.lowrank else None)
-        return md, kd, ld
-
-    def _apply(self, state: EngineState, deltas) -> EngineState:
-        md, kd, ld = deltas
-        return EngineState(
-            moments=(acc.moment_apply(state.moments, md)
-                     if md is not None else state.moments),
-            kmeans=(acc.kmeans_apply(state.kmeans, kd, decay=self.kmeans.decay)
-                    if kd is not None else state.kmeans),
-            lowrank=(lowrank_mod.range_apply(state.lowrank, ld)
-                     if ld is not None else state.lowrank),
-            reassign=state.reassign,
-        )
+    def folds(self) -> tuple:
+        """``(field, descriptor, aux)`` for each :class:`EngineState` field
+        this engine accumulates: the :mod:`repro.stream.step` fold of each."""
+        out = []
+        if self.lowrank:
+            out.append(("lowrank", ("range", self.impl), self._omega))
+        else:
+            out.append(("moments", ("moment", self.cov_path), None))
+        if self.kmeans is not None:
+            out.append(("kmeans", ("kmeans", self.kmeans.track_reassignments,
+                                   self.kmeans.decay), None))
+        return tuple(out)
 
     def _build_update(self):
         """update(state, x (n_shards, b, p), step) → state, single-device or
         shard_map'd; both fold the same per-(step, shard) sketches.
 
-        With ``track_reassignments`` the update ALSO re-assigns each shard's
-        rows under the post-apply centers and compares to the pre-apply labels
-        (already computed inside the K-means delta) — the (r,) counts travel
-        in ``state.reassign`` and, under a mesh, ride one extra int psum."""
-        track = self.kmeans is not None and self.kmeans.track_reassignments
+        With ``track_reassignments`` the K-means fold also counts the rows
+        whose nearest center moved across the apply; the (r,) counts travel
+        in ``state.reassign`` and, under a mesh, take the deltas' psum."""
+        mesh, axis, folds = self.mesh, self.axis, self.folds()
+
+        def psum(tree):
+            with jax.named_scope("obs.psum"):
+                return jax.lax.psum(tree, axis)  # the only cross-shard traffic
 
         # jax.named_scope annotations: zero-cost trace-time names, so an XLA
         # profile splits the fused device step into sketch / fold / psum —
         # the in-jit counterpart of the host-side obs.span timings.
-        def local_deltas(state, x, step, shard):
+        def update(state, x, step):
+            shards = (range(self.n_shards) if mesh is None
+                      else [jax.lax.axis_index(axis)])
             with jax.named_scope("obs.sketch"):
-                s = self._sketch_local(x, step, shard)
+                sketches = [self.sketch_shard(x[i], step, sh)
+                            for i, sh in enumerate(shards)]
+            new = {}
             with jax.named_scope("obs.fold"):
-                return self._deltas(state, s)
+                for field, desc, aux in folds:
+                    new[field], counts = step_mod.fold_step(
+                        desc, getattr(state, field), aux, sketches,
+                        reduce=None if mesh is None else psum)
+                    if field == "kmeans" and state.reassign is not None:
+                        new["reassign"] = (state.reassign[0] + counts, counts)
+            return dataclasses.replace(state, **new)
 
-        def local_deltas_tracked(state, x, step, shard):
-            with jax.named_scope("obs.sketch"):
-                s = self._sketch_local(x, step, shard)
-            with jax.named_scope("obs.fold"):
-                md = (None if self.lowrank
-                      else acc.moment_delta(s, track_cov=self.track_cov,
-                                            cov_path=self.cov_path))
-                kd, a0 = acc.kmeans_delta_with_assign(state.kmeans, s)
-                ld = (lowrank_mod.range_delta(s, self._omega, impl=self.impl)
-                      if self.lowrank else None)
-            return (md, kd, ld), (s, a0)
-
-        def with_counts(state: EngineState, cnt) -> EngineState:
-            return dataclasses.replace(state,
-                                       reassign=(state.reassign[0] + cnt, cnt))
-
-        if self.mesh is None:
-            if not track:
-                def update(state, x, step):
-                    # same semantics as the psum path: every shard's delta is
-                    # taken against the step-start state, summed, applied once.
-                    deltas = local_deltas(state, x[0], step, 0)
-                    for shard in range(1, self.n_shards):
-                        d = local_deltas(state, x[shard], step, shard)
-                        deltas = jax.tree.map(jnp.add, deltas, d)
-                    return self._apply(state, deltas)
-                return update
-
-            def update(state, x, step):
-                deltas = None
-                pairs = []
-                for shard in range(self.n_shards):
-                    d, pair = local_deltas_tracked(state, x[shard], step, shard)
-                    deltas = d if deltas is None else jax.tree.map(jnp.add, deltas, d)
-                    pairs.append(pair)
-                new = self._apply(state, deltas)
-                cnt = jnp.zeros_like(state.reassign[1])
-                for s, a0 in pairs:
-                    cnt = cnt + acc.kmeans_reassigned(new.kmeans, s, a0)
-                return with_counts(new, cnt)
+        if mesh is None:
             return update
-
-        axis = self.axis
-        state_spec = P()  # replicated accumulators; deltas psum'd each step
-
-        if not track:
-            def sharded_update(state, x, step):
-                deltas = local_deltas(state, x[0], step, jax.lax.axis_index(axis))
-                with jax.named_scope("obs.psum"):
-                    deltas = jax.lax.psum(deltas, axis)  # the only cross-shard traffic
-                return self._apply(state, deltas)
-        else:
-            def sharded_update(state, x, step):
-                deltas, (s, a0) = local_deltas_tracked(
-                    state, x[0], step, jax.lax.axis_index(axis))
-                with jax.named_scope("obs.psum"):
-                    deltas = jax.lax.psum(deltas, axis)
-                new = self._apply(state, deltas)
-                cnt = jax.lax.psum(acc.kmeans_reassigned(new.kmeans, s, a0), axis)
-                return with_counts(new, cnt)
-
         # check_vma=False: the Pallas kernels inside declare no per-axis
         # varying type for their outputs
-        return shard_map(
-            sharded_update, mesh=self.mesh,
-            in_specs=(state_spec, P(axis), state_spec),
-            out_specs=state_spec, check_vma=False,
-        )
+        return shard_map(update, mesh=mesh, in_specs=(P(), P(axis), P()),
+                         out_specs=P(), check_vma=False)
 
     # ------------------------------------------------------------- running --
 
@@ -395,7 +340,7 @@ class StreamEngine:
         if self.kmeans is not None:
             x0 = self._host_global_batch(seed, 0, device_put=False)
             # shard id n_shards is never used by the stream — an independent mask
-            s0 = self._sketch_local(x0.reshape(-1, x0.shape[-1]), jnp.int32(0), self.n_shards)
+            s0 = self.sketch_shard(x0.reshape(-1, x0.shape[-1]), jnp.int32(0), self.n_shards)
             km = acc.kmeans_init(fold_in_str(self.spec.key, "stream-kmeans"), s0,
                                  self.kmeans.k, self.kmeans.n_init,
                                  decay=self.kmeans.decay)
@@ -597,7 +542,7 @@ class StreamEngine:
         km = None
         if self.kmeans is not None:
             x0 = jnp.asarray(xs[0]).reshape(-1, xs.shape[-1])
-            s0 = self._sketch_local(x0, jnp.int32(0), self.n_shards)
+            s0 = self.sketch_shard(x0, jnp.int32(0), self.n_shards)
             km = acc.kmeans_init(fold_in_str(self.spec.key, "stream-kmeans"), s0,
                                  self.kmeans.k, self.kmeans.n_init,
                                  decay=self.kmeans.decay)
@@ -618,7 +563,7 @@ class StreamEngine:
         has_lr, has_km = self.lowrank, self.kmeans is not None
 
         def local_deltas(x, step, shard, q_mat, frozen, prev):
-            s = self._sketch_local(x, step, shard)
+            s = self.sketch_shard(x, step, shard)
             ld = (lowrank_mod.range_delta(s, q_mat, impl=self.impl)
                   if has_lr else None)
             kd = refine_mod.kmeans2_delta(s, frozen, prev) if has_km else None

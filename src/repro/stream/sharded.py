@@ -1,18 +1,12 @@
 """shard_map one-shot reductions over row-sharded sketches.
 
-These are the batch (non-streaming) entry points of the same delta/psum algebra
-the StreamEngine loops: each shard computes its local accumulator delta from its
-rows, and the only collective is one psum of the fixed-size delta — (p,) for the
-mean, (p, p) for the covariance — regardless of how many rows each shard holds.
-(These absorbed the former ``repro.core.distributed`` shims: this module is
-the one home of the distributed one-pass setting, ``repro.api`` the front
-door over it.)
-
-The ``repro.api`` sharded backend also streams THROUGH :func:`sharded_moments`:
-its moment reducer buffers one step's shard sketches, reduces them with a
-single call (one psum of the fixed-size delta), folds the result via
-``moment_apply``, and drops the sketches — per-step streaming reduction, so
-host memory stays constant in the stream length.
+Each shard computes its local accumulator delta from its rows, and the only
+collective is one psum of the fixed-size delta — (p,) for the mean, (p, p)
+for the covariance — regardless of how many rows each shard holds. The
+``repro.api`` sharded backend streams through these, one step at a time, and
+its minibatch K-means step is :func:`repro.stream.step.fold_step` under
+``shard_map`` (:func:`sharded_kmeans_step`). The distributed-data entry points
+at the end place rows on a mesh, sketch them there, and run Lloyd over them.
 """
 from __future__ import annotations
 
@@ -26,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.sampling import SparseRows
 from repro.lowrank import range_finder as lr_range
 from repro.stream import accumulators as acc
+from repro.stream import step
 
 
 @functools.lru_cache(maxsize=None)
@@ -127,44 +122,18 @@ def sharded_cov(s: SparseRows, mesh, axes=("data",)) -> jax.Array:
 
 
 @functools.lru_cache(maxsize=None)
-def _kmeans_step_fn(mesh, axis, p, decay, track):
-    """Compiled mini-batch K-means step: local masked delta per shard, ONE psum
-    of the fixed-size (sums, cnts, obj, n) delta, apply on replicated state.
-    Cached per (mesh, axis, p, decay, track) so streaming callers compile once."""
-    from repro.core.kmeans import sparse_sq_dists
+def _kmeans_step_fn(mesh, axis, p, decay, track, *algebra):
+    """Compiled mini-batch K-means step: the step fold under shard_map, its
+    delta and reassignment counts psum'd over ``axis``. Cached per static
+    description and per :func:`repro.stream.step.algebra`, so streaming
+    callers compile once and a replaced accumulator function is traced."""
+    desc = ("kmeans", track, decay)
 
     def local(state, values, indices, mask):
-        k = state.centers.shape[1]
-        maskf = mask.astype(jnp.float32)
-        maski = jnp.broadcast_to(mask.astype(jnp.int32)[:, None], indices.shape)
-
-        def one(centers):
-            d = sparse_sq_dists(values, indices, centers)        # (n, K)
-            a = jnp.argmin(d, axis=1)
-            rows = jnp.broadcast_to(a[:, None], indices.shape)
-            # Zero-pad rows are REAL points at the origin to the scatter adds
-            # (unlike the linear moment deltas) — the mask zeroes their
-            # values, counts, and objective contributions explicitly.
-            sums = jnp.zeros((k, p), jnp.float32).at[rows, indices].add(
-                values.astype(jnp.float32) * maskf[:, None])
-            cnts = jnp.zeros((k, p), jnp.int32).at[rows, indices].add(maski)
-            obj = jnp.sum(jnp.min(d, axis=1) * maskf).astype(jnp.float32)
-            return sums, cnts, obj, a.astype(jnp.int32)
-
-        sums, cnts, obj, assign = jax.vmap(one)(state.centers)
-        delta = jax.lax.psum(
-            (sums, cnts, obj, jnp.sum(mask).astype(jnp.int32)), axis)
-        new = acc.kmeans_apply(state, delta, decay)
-        if not track:
-            return new
-
-        def reassigned(c_new, a_prev):
-            a1 = jnp.argmin(sparse_sq_dists(values, indices, c_new), axis=1)
-            return jnp.sum((a1.astype(jnp.int32) != a_prev)
-                           * mask.astype(jnp.int32)).astype(jnp.int32)
-
-        cnt = jax.lax.psum(jax.vmap(reassigned)(new.centers, assign), axis)
-        return new, cnt
+        new, counts = step.fold_step(
+            desc, state, None, [SparseRows(values, indices, p)],
+            reduce=lambda t: jax.lax.psum(t, axis), mask=mask)
+        return (new, counts) if track else new
 
     row_spec = P(axis, None)
     out = (P(), P()) if track else P()
@@ -178,11 +147,11 @@ def sharded_kmeans_step(state: acc.KMeansState, s: SparseRows, mesh,
                         track_reassignments: bool = False, mask=None):
     """One streaming mini-batch K-means step over a row-sharded step sketch.
 
-    The mesh-resident analogue of ``kmeans_delta`` + ``kmeans_apply``:
-    assignment stays local to each shard, the only collective is one psum of
-    the fixed-size delta, and the Eq.-39 apply (with ``decay``) runs once on
-    the replicated state — so sharded streaming matches the host loop to
-    float-summation reordering. Returns ``(new_state, reassigned)`` where
+    :func:`repro.stream.step.fold_step` over a mesh: assignment stays local
+    to each shard, the only collective is one psum of the fixed-size delta,
+    and the Eq.-39 apply (with ``decay``) runs once on the replicated state —
+    so sharded streaming matches the host loop to float-summation
+    reordering. Returns ``(new_state, reassigned)`` where
     ``reassigned`` is the psum'd (r,) int32 reassignment count when
     ``track_reassignments`` (one extra assignment pass under the NEW centers),
     else ``None``.
@@ -203,7 +172,7 @@ def sharded_kmeans_step(state: acc.KMeansState, s: SparseRows, mesh,
             indices = jnp.pad(indices, ((0, pad), (0, 0)))
             mask = jnp.pad(mask, (0, pad))
     fn = _kmeans_step_fn(mesh, axis, s.p, float(decay),
-                         bool(track_reassignments))
+                         bool(track_reassignments), *step.algebra())
     out = fn(state, values, indices, mask)
     return out if track_reassignments else (out, None)
 

@@ -207,3 +207,151 @@ def test_sharded_streaming_matches_single_device():
                        cwd=os.path.dirname(os.path.dirname(__file__)),
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
+
+
+# -------------------------------------------------------- the step fold ---
+
+_FOLD_SCRIPT = """
+import json
+import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
+from repro import lowrank
+from repro.core import sketch
+from repro.core.sampling import SparseRows
+from repro.stream import accumulators as acc, sharded, step
+
+mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
+p, b = 128, 24
+spec = sketch.make_spec(p, jax.random.PRNGKey(1), gamma=0.25)
+x = jax.random.normal(jax.random.PRNGKey(0), (8, b, p))
+sk = [sketch.sketch(x[i], spec, batch_key=sketch.batch_key(spec, 3, i)) for i in range(8)]
+values = jnp.stack([s.values for s in sk])
+indices = jnp.stack([s.indices for s in sk])
+km0 = acc.kmeans_init(jax.random.PRNGKey(2), sk[0], 4, 2)
+folds = {
+    "moment": (("moment", "dense"), acc.moment_init(spec.p_pad, track_cov=True), None),
+    "range": (("range", "auto"), lowrank.range_init(spec.p_pad, 6),
+              lowrank.omega(spec.key, spec.p_pad, 6)),
+    "kmeans": (("kmeans", True, 1.0), km0, None),
+}
+
+def diff(a, b):
+    # what |a - b| <= atol + rtol·|b| leaves of atol, at rtol 1e-5: the
+    # objective sums many distances, so its float reordering is relative
+    return max(float(jnp.max(jnp.abs(u - v) - 1e-5 * jnp.abs(v)))
+               for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b))
+               for u, v in [(jnp.asarray(u, jnp.float32), jnp.asarray(v, jnp.float32))])
+
+report = {}
+for kind, (desc, carry, aux) in folds.items():
+    host, host_counts = step.fold_step(desc, carry, aux, sk)
+
+    def body(carry, v, i, desc=desc, aux=aux):
+        return step.fold_step(desc, carry, aux, [SparseRows(v[0], i[0], spec.p_pad)],
+                              reduce=lambda t: jax.lax.psum(t, "data"))
+
+    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+                           out_specs=P(), check_vma=False))
+    got, got_counts = fn(carry, values, indices)
+    report[kind] = {"diff": diff(host, got),
+                    "counts": [np.asarray(host_counts).tolist(), np.asarray(got_counts).tolist()],
+                    "count": [int(host.count), int(got.count)]}
+
+# 100 rows over 8 shards: 4 masked pad rows
+s100 = sketch.sketch(x.reshape(-1, p)[:100], spec, batch_key=sketch.batch_key(spec, 3, 0))
+host, host_counts = step.fold_step(("kmeans", True, 1.0), km0, None, [s100])
+got, got_counts = sharded.sharded_kmeans_step(km0, s100, mesh, track_reassignments=True)
+report["masked"] = {"diff": diff(host, got),
+                    "counts": [np.asarray(host_counts).tolist(), np.asarray(got_counts).tolist()],
+                    "count": [int(host.count), int(got.count)]}
+print(json.dumps(report))
+"""
+
+
+@pytest.fixture(scope="module")
+def fold_report():
+    """One 8-host-device interpreter computes every case below."""
+    import json
+
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", _FOLD_SCRIPT], env=env,
+                       cwd=os.path.dirname(os.path.dirname(__file__)),
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("kind", ["moment", "range", "kmeans"])
+def test_fold_step_psum_matches_host(fold_report, kind):
+    """fold_step under shard_map with a psum over 8 devices == the host
+    fold_step over the same 8 shard sketches (atol 1e-5, rtol 1e-5;
+    reassignment counts exact)."""
+    got = fold_report[kind]
+    assert got["diff"] <= 1e-5, got
+    assert got["counts"][0] == got["counts"][1]
+    assert got["count"] == [8 * 24, 8 * 24]
+
+
+def test_sharded_kmeans_step_masks_pad_rows(fold_report):
+    """100 rows over 8 shards: the 4 pad rows move no center, count no
+    reassignment and add no row, so the step == the host fold on 100 rows."""
+    got = fold_report["masked"]
+    assert got["diff"] <= 1e-5, got
+    assert got["counts"][0] == got["counts"][1]
+    assert got["count"] == [100, 100]
+
+
+def test_fd_fold_refuses_reduce():
+    """Frequent Directions is order-dependent: no cross-shard reduce."""
+    from repro import lowrank
+    from repro.stream import step
+
+    s = sketch.sketch(jax.random.normal(KEY, (8, 64)), sketch.make_spec(64, KEY, gamma=0.25))
+    carry = lowrank.fd_init(64, 4)
+    folded, _ = step.fold_step(("fd",), carry, None, [s])
+    assert int(folded.count) == 8
+    with pytest.raises(ValueError, match="order-dependent"):
+        step.fold_step(("fd",), carry, None, [s], reduce=lambda t: t)
+
+
+def test_planted_kmeans_apply_reaches_every_driver(monkeypatch):
+    """A K-means apply replaced on its module (as the benchmark's planted
+    faults do) is the one every driver runs: the engine, the sharded step
+    and the api's scan all leave their centers where they started."""
+    from jax.sharding import Mesh
+
+    from repro.api import Plan, SparsifiedKMeans, fit_many
+    from repro.stream import accumulators as acc
+    from repro.stream import sharded
+
+    def kmeans_apply(state, delta, decay=1.0):
+        return type(state)(state.centers, state.counts, state.obj, state.count + delta[3])
+
+    monkeypatch.setattr(acc, "kmeans_apply", kmeans_apply)
+    p, b = 64, 32
+    spec = sketch.make_spec(p, jax.random.PRNGKey(6), gamma=0.25)
+    data = np.asarray(jax.random.normal(KEY, (3, 2, b, p)))
+
+    eng = StreamEngine(spec, lambda seed, step, shard: data[step, shard], n_shards=2,
+                       kmeans=StreamKMeansConfig(k=3, n_init=2, track_reassignments=True))
+    start = eng.init_state()
+    eng.run(3)
+    np.testing.assert_array_equal(eng.state.kmeans.centers, start.kmeans.centers)
+    assert int(eng.state.kmeans.count) == 3 * 2 * b
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    s = sketch.sketch(data[0, 0], spec)
+    new, _ = sharded.sharded_kmeans_step(start.kmeans, s, mesh, track_reassignments=True)
+    np.testing.assert_array_equal(new.centers, start.kmeans.centers)
+    assert int(new.count) == b
+
+    plan = Plan(backend="stream", gamma=0.25, batch_size=b)
+    rows = data.reshape(-1, p)
+    one, three = (SparsifiedKMeans(3, plan, key=1, algorithm="minibatch")
+                  for _ in range(2))
+    fit_many(plan, [one], rows[:b], scan=True)
+    fit_many(plan, [three], rows, scan=True)
+    np.testing.assert_array_equal(three.centers_pre_, one.centers_pre_)
+    assert float(three.objective_) == 0.0 and three.count_ == 6 * b
